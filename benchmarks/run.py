@@ -26,6 +26,8 @@ import os
 import shutil
 import time
 
+from repro.runtime.compile_cache import use_compile_cache
+
 from . import (
     bench_budget_sweep,
     bench_codesign,
@@ -72,6 +74,7 @@ def _mirror_bench_json() -> None:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", nargs="*", choices=sorted(BENCHES), default=None)
     ap.add_argument(

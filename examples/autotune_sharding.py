@@ -10,6 +10,7 @@ from repro.configs.base import SHAPES
 from repro.configs.registry import arch_names, get_config
 from repro.launch.autotune import autotune
 from repro.roofline.analytic import MeshShape, model_flops
+from repro.runtime.compile_cache import use_compile_cache
 from repro.sharding.rules import DistConfig
 
 
@@ -22,6 +23,7 @@ def baseline_rules():
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=arch_names(), default="qwen3-1.7b")
     ap.add_argument("--shape", choices=sorted(SHAPES), default="train_4k")

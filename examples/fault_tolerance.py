@@ -15,6 +15,7 @@ from repro.configs.registry import reduced_config
 from repro.data.pipeline import for_model
 from repro.models.model import RunFlags
 from repro.optim.adamw import AdamWConfig
+from repro.runtime.compile_cache import use_compile_cache
 from repro.runtime.health import Supervisor
 from repro.train.step import init_train_state, make_train_step
 
@@ -46,6 +47,7 @@ def run(workdir: str, inject_failure: bool):
 
 
 def main() -> None:
+    use_compile_cache()
     d1, d2 = tempfile.mkdtemp(), tempfile.mkdtemp()
     try:
         print("reference run (no failure):")

@@ -18,9 +18,11 @@ from repro.core import (
     calibrated_budget,
     simulate,
 )
+from repro.runtime.compile_cache import use_compile_cache
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--iterations", type=int, default=500)
     ap.add_argument("--awareness", choices=AWARENESS_LEVELS, default="farsi")

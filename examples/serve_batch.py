@@ -19,10 +19,12 @@ from repro.core import (
     audio,
     calibrated_budget,
 )
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serve import DseService
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--sessions", type=int, default=12,
                     help="total sessions (half admitted up front, half join "
@@ -72,8 +74,12 @@ def main() -> None:
           f"{stats.n_ticks} ticks, {stats.wall_s:.2f}s "
           f"({stats.evals_per_s:,.0f} evals/s aggregate) ==")
     for h in handles:
+        if h.failed:
+            print(f"  {h.name:<16s} FAILED: {h.error!r}")
+            continue
         r = h.result
         print(f"  {h.name:<16s} iters={r.iterations:3d} "
+              f"{'DEGRADED ' if h.degraded else ''}"
               f"converged={str(r.converged):<5s} "
               f"distance={r.best_distance.city_block():8.3f}  "
               f"blocks={r.best_design.block_counts()}  "
@@ -83,7 +89,9 @@ def main() -> None:
           f"hit-rate={stats.cache_hit_rate:.1%}")
     print(f"session latency: p50={stats.latency_percentile(50):.2f}s "
           f"p95={stats.latency_percentile(95):.2f}s; "
-          f"fallback evals: {stats.n_fallback}")
+          f"fallback evals: {stats.n_fallback}, "
+          f"degraded sessions: {stats.n_degraded}, "
+          f"failed sessions: {stats.n_failed}")
 
 
 if __name__ == "__main__":

@@ -22,6 +22,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import for_model
 from repro.models.model import RunFlags
 from repro.optim.adamw import AdamWConfig
+from repro.runtime.compile_cache import use_compile_cache
 from repro.runtime.health import Supervisor
 from repro.train.step import init_train_state, make_train_step
 
@@ -55,6 +56,7 @@ def make_config(p) -> ModelConfig:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", choices=PRESETS, default="2m")
     ap.add_argument("--steps", type=int, default=200)

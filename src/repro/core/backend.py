@@ -98,6 +98,9 @@ class BackendStats:
     # the serve layer's non-finite guard rejects these; a nonzero count on a
     # healthy backend means a numerical escape worth investigating
     n_nonfinite_rows: int = 0
+    # the Pallas kernel runs in interpret mode (CPU only): it prices the
+    # same math through the host interpreter instead of a Mosaic launch
+    kernel_interpret: bool = False
     wall_s: float = 0.0  # total time inside evaluate()
     encode_s: float = 0.0  # incremental encoding into batch buffers
     dispatch_s: float = 0.0  # XLA dispatch submission
@@ -742,8 +745,9 @@ class JaxBatchedBackend:
       * ``use_kernel=True`` — the fused Pallas launch
         (`repro.kernels.phase_sim`): one kernel over the (B, T) grid with
         the co-residency masks in VMEM scratch (Mosaic on TPU, interpret
-        mode elsewhere — interpret trades speed for exercising the real
-        kernel path, which is why CPU defaults to the XLA reference).
+        mode on CPU — recorded as ``stats().kernel_interpret``; interpret
+        trades speed for exercising the real kernel path, which is why CPU
+        defaults to the XLA reference).
 
     ``use_kernel=None`` resolves from ``REPRO_PHASE_SIM_KERNEL`` (``1``
     forces the kernel, ``0`` forbids it) and otherwise turns it on exactly
@@ -783,7 +787,10 @@ class JaxBatchedBackend:
             else:
                 use_kernel = jax.default_backend() == "tpu"
         self._use_kernel = bool(use_kernel)
-        self._interpret = jax.default_backend() != "tpu"
+        # Mosaic compiles the kernel for TPU only; the CPU interprets it,
+        # and any other platform fails to compile it rather than silently
+        # falling back to the interpreter
+        self._interpret = self._use_kernel and jax.default_backend() == "cpu"
         if self._use_kernel:
             self.name = "jax_pallas"
         self._jit = None  # single kernel: shapes vary only via padded buckets
@@ -806,7 +813,7 @@ class JaxBatchedBackend:
         # the design ref doubles as an identity guard against id() reuse
         self._adopted: Dict[int, tuple] = {}
         self._shapes: set = set()
-        self._stats = BackendStats()
+        self._stats = BackendStats(kernel_interpret=self._interpret)
         # device-resident chain runner (device_explore) — built lazily so
         # host-loop users never pay for it; shares the workload encoding
         self._chains = None
@@ -1408,7 +1415,7 @@ BACKENDS = {
     "python": PythonBackend,
     "jax": JaxBatchedBackend,
     "jax_batched": JaxBatchedBackend,
-    # fused Pallas phase-sim kernel (Mosaic on TPU; interpret mode elsewhere,
+    # fused Pallas phase-sim kernel (Mosaic on TPU; interpret mode on CPU,
     # so on CPU prefer "jax" for speed and this for kernel-path coverage)
     "pallas": _jax_pallas_backend,
     "jax_pallas": _jax_pallas_backend,

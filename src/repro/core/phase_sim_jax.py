@@ -843,7 +843,7 @@ def simulate_batch(  # repro: traced
 
     This is the XLA *reference* path; ``repro.kernels.phase_sim`` provides
     the fused Pallas formulation of the same math (one launch over the
-    (B, T) grid, Mosaic on TPU / interpret elsewhere) selected via
+    (B, T) grid, Mosaic on TPU / interpret on CPU) selected via
     ``JaxBatchedBackend(use_kernel=True)``.
     """
     return jax.vmap(lambda row: simulate_one(enc, row))(rows)

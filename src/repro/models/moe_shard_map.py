@@ -30,23 +30,6 @@ from jax.sharding import PartitionSpec as P
 from ..configs.base import ModelConfig
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-tolerant shard_map: the API moved from
-    ``jax.experimental.shard_map`` to top-level ``jax.shard_map``, and its
-    replication-check kwarg was renamed ``check_rep`` → ``check_vma`` along
-    the way. We disable the check under whichever spelling exists."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    for check_kw in ("check_vma", "check_rep"):
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{check_kw: False})
-        except TypeError:
-            continue
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
 def _local_dispatch(xf, probs, k: int, c_loc: int, e: int):
     """Local capacity dispatch over this shard's tokens.
     xf: (T_loc, D); probs: (T_loc, E) → (grouped (E, C_loc, D), slot, keep, gates)."""
@@ -132,10 +115,11 @@ def moe_apply_shard_map(
         y = y_rep.reshape(bl * sl, k, d).sum(axis=1)
         return y.reshape(bl, sl, d), aux
 
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         block,
         mesh=mesh,
         in_specs=(x_spec, P(None, None), w_in_spec, w_in_spec, w_out_spec),
         out_specs=(x_spec, P()),
+        check_vma=False,
     )(x, params["router"], params["wi_gate"], params["wi_up"], params["wo"])
     return y, aux

@@ -25,7 +25,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_benchmarks_smoke_cli():
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # the persistent compilation cache stays off under test
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_ENABLE_COMPILATION_CACHE="false")
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.run", "--smoke"],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=480,

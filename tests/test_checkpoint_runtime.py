@@ -120,6 +120,30 @@ def test_heartbeat_dead_detection(tmp_path):
     assert Heartbeat.dead_hosts(str(tmp_path), timeout_s=10, now=now + 100) == [0, 1]
 
 
+def test_compile_cache_dir_is_fixed_unless_set_from_outside(monkeypatch, tmp_path):
+    """Without ``JAX_COMPILATION_CACHE_DIR`` the persistent cache goes to
+    ``.jax_cache/`` at the checkout root; with it, the directory JAX read
+    from the variable stands. Nothing compiles here, so the cache stays
+    unused."""
+    from repro.runtime import compile_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.realpath(__file__)))
+    assert compile_cache.CACHE_DIR == os.path.join(repo, ".jax_cache")
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert compile_cache.use_compile_cache() == compile_cache.CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == compile_cache.CACHE_DIR
+        outside = str(tmp_path / "cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        # what JAX reads from the variable when it starts
+        jax.config.update("jax_compilation_cache_dir", outside)
+        assert compile_cache.use_compile_cache() == outside
+        assert jax.config.jax_compilation_cache_dir == outside
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
 def test_supervisor_recovers_and_matches_uninterrupted_run(tmp_path, rng_key):
     """Kill the step function mid-run; the supervisor restores the last
     checkpoint and the final state matches a run with no failure

@@ -24,13 +24,14 @@ x = jax.random.normal(key, (2, 16, cfg.d_model))
 y_d, aux_d = moe_apply(p, x, cfg)
 rules = {"batch": ("data",), "seq_res": None}
 
-mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+AUTO = (jax.sharding.AxisType.Auto,) * 2
+mesh1 = jax.make_mesh((1, 1), ("data", "model"), axis_types=AUTO)
 with mesh1:
     y1, a1 = jax.jit(lambda p_, x_: moe_apply_shard_map(p_, x_, cfg, mesh1, rules))(p, x)
 assert float(jnp.abs(y1 - y_d).max()) < 1e-5, "1x1 mismatch"
 assert abs(float(a1) - float(aux_d)) < 1e-5
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"), axis_types=AUTO)
 xs = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
 with mesh:
     y2, a2 = jax.jit(lambda p_, x_: moe_apply_shard_map(p_, x_, cfg, mesh, rules))(p, xs)

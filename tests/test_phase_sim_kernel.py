@@ -21,7 +21,8 @@ KERNEL_REL_TOL = 1e-5  # acceptance bar: Pallas vs ref parity
 _CHECK_KEYS = (
     "latency_s", "finish_s", "bneck_code", "bneck_kind_s", "alp_time_s",
     "traffic_bytes", "n_phases", "wl_latency_s", "energy_j", "power_w",
-    "area_mm2", "fitness", "all_done",
+    "area_mm2", "fitness", "all_done", "pe_bneck_s", "mem_bneck_s",
+    "noc_bneck_s", "top_bneck_pe", "top_bneck_mem",
 )
 
 
@@ -32,6 +33,20 @@ _CHECK_KEYS = (
 def test_kernel_matches_ref_oracle(graph_fn, batch):
     """Interpret-mode kernel vs the pure-jnp oracle, every output column,
     ≤ 1e-5 relative — including the Eq.-7 fitness the explorer ranks by."""
+    _assert_kernel_matches_ref_oracle(graph_fn, batch)
+
+
+def test_kernel_matches_ref_oracle_at_mosaic_lane_width(monkeypatch):
+    """The same parity with the task axis padded to the 128 lanes Mosaic
+    compiles (interpret mode otherwise pads to 8): the chip's layout, with
+    100 padded tasks on ``ar_complex``, exercised on CPU."""
+    from repro.kernels.phase_sim import ops
+
+    monkeypatch.setattr(ops, "INTERPRET_LANE", ops.LANE)
+    _assert_kernel_matches_ref_oracle(ar_complex, 8)
+
+
+def _assert_kernel_matches_ref_oracle(graph_fn, batch):
     import jax
 
     from repro.kernels.phase_sim import phase_sim, phase_sim_ref
