@@ -194,6 +194,7 @@ def run(smoke: bool = False) -> List[Row]:
         breakdown = {
             "encode_s_per_dispatch": (s1.encode_s - s0.encode_s) / n_disp,
             "dispatch_s_per_dispatch": (s1.dispatch_s - s0.dispatch_s) / n_disp,
+            "fetch_wait_s_per_dispatch": (s1.fetch_wait_s - s0.fetch_wait_s) / n_disp,
             "decode_s_per_dispatch": (s1.decode_s - s0.decode_s) / n_disp,
             "n_compiles": s1.n_compiles,
         }
@@ -537,6 +538,7 @@ def run(smoke: bool = False) -> List[Row]:
                 f"simbackend.{g.name}.breakdown",
                 0.0,
                 "encode={encode_s_per_dispatch:.2e}s dispatch={dispatch_s_per_dispatch:.2e}s "
+                "fetch={fetch_wait_s_per_dispatch:.2e}s "
                 "decode={decode_s_per_dispatch:.2e}s compiles={n_compiles} "
                 "kernel={kernel_dispatch_wall_s:.2e}s "
                 "ref={ref_dispatch_wall_s:.2e}s".format(**breakdown),

@@ -38,11 +38,11 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import time
 from typing import Dict, Iterator, List, Optional, Protocol, Sequence, runtime_checkable
 
 import numpy as np
 
+from ..runtime.spans import span
 from .blocks import BlockKind
 from .budgets import Budget, Distance, distance
 from .database import HardwareDatabase
@@ -67,25 +67,22 @@ _BNECK_KINDS = ("pe", "mem", "noc")
 class BackendStats:
     """Evaluation accounting — the backend owns n_sims and sim wall-clock.
 
-    ``wall_s`` covers time inside ``evaluate``/``evaluate_candidates``;
-    the encode/dispatch/decode breakdown splits the JAX hot path: host-side
-    delta encoding into the batch buffers, XLA dispatch submission (async —
-    device time is hidden behind it), and lazy ``SimResult`` reconstruction
-    (paid per *accessed* handle, possibly after the dispatch returns, so
-    ``decode_s`` is not a subset of ``wall_s``).
-
-    ``n_inflight_max`` is the deepest the dispatch pipeline ever got: the
-    number of dispatches simultaneously un-consumed on device. ≥ 2 means a
-    later batch was encoded+submitted while an earlier one was still being
-    scored — the host-encode/device-compute overlap multi-session serving
-    relies on (many sessions' batches in flight at once)."""
+    ``wall_s`` covers time inside ``evaluate``/``evaluate_candidates``
+    (and ``run_chains``); the encode/dispatch/fetch/decode breakdown splits
+    the JAX hot path: host-side delta encoding into the batch buffers, XLA
+    dispatch submission (async — device time is hidden behind it), the
+    first fetch of a batch's outputs (the wait for the device, then the
+    transfer), and the host decode of fetched outputs into scalars,
+    telemetry and lazy ``SimResult`` s (paid per *accessed* handle, possibly
+    after the dispatch returns, so neither ``fetch_wait_s`` nor
+    ``decode_s`` is a subset of ``wall_s``). Each time field is summed by a
+    host span (``repro.runtime.spans``), named beside the field."""
 
     n_sims: int = 0  # designs evaluated (cache-served candidates included)
     n_dispatches: int = 0  # evaluate() calls
     n_batched: int = 0  # designs through the vectorized path
     n_fallback: int = 0  # designs through the scalar Python path
     n_compiles: int = 0  # distinct padded shapes seen by the jit cache
-    n_inflight_max: int = 0  # deepest concurrent-dispatch pipeline seen
     # content-addressed evaluation cache (serve.DesignStore, when attached):
     # hits never dispatch a device row — they are served from a memoized row
     # of an earlier identical (encoding, workload, budget) evaluation or
@@ -101,10 +98,13 @@ class BackendStats:
     # the Pallas kernel runs in interpret mode (CPU only): it prices the
     # same math through the host interpreter instead of a Mosaic launch
     kernel_interpret: bool = False
-    wall_s: float = 0.0  # total time inside evaluate()
-    encode_s: float = 0.0  # incremental encoding into batch buffers
-    dispatch_s: float = 0.0  # XLA dispatch submission
-    decode_s: float = 0.0  # lazy SimResult reconstruction + score fetches
+    # total time inside the entries (spans backend.designs, backend.candidates,
+    # backend.run_chains)
+    wall_s: float = 0.0
+    encode_s: float = 0.0  # incremental encoding into batch buffers (backend.encode)
+    dispatch_s: float = 0.0  # XLA dispatch submission (backend.dispatch)
+    fetch_wait_s: float = 0.0  # first fetch of each batch's outputs (backend.fetch_wait)
+    decode_s: float = 0.0  # host decode of fetched outputs (backend.decode)
 
 
 @dataclasses.dataclass
@@ -513,23 +513,21 @@ class PythonBackend:
         """Synchronous backend: every evaluate() already returned results."""
 
     def evaluate(self, designs: Sequence[Design]) -> List[SimResult]:
-        t0 = time.perf_counter()
-        out = [simulate(d, self.tdg, self.db) for d in designs]
+        with span("backend.designs", stats=self._stats, field="wall_s"):
+            out = [simulate(d, self.tdg, self.db) for d in designs]
         self._stats.n_sims += len(out)
         self._stats.n_dispatches += 1
-        self._stats.wall_s += time.perf_counter() - t0
         return out
 
     def evaluate_candidates(self, cands: Sequence[Candidate]) -> List[SimHandle]:
-        t0 = time.perf_counter()
-        out: List[SimHandle] = []
-        for c in cands:
-            with c.materialized(self.tdg) as d:
-                res = simulate(d, self.tdg, self.db)
-            out.append(_ReadyHandle(res, _host_fitness(res, c), c, self.tdg))
+        with span("backend.candidates", stats=self._stats, field="wall_s"):
+            out: List[SimHandle] = []
+            for c in cands:
+                with c.materialized(self.tdg) as d:
+                    res = simulate(d, self.tdg, self.db)
+                out.append(_ReadyHandle(res, _host_fitness(res, c), c, self.tdg))
         self._stats.n_sims += len(out)
         self._stats.n_dispatches += 1
-        self._stats.wall_s += time.perf_counter() - t0
         return out
 
     def stats(self) -> BackendStats:
@@ -598,31 +596,36 @@ class _JaxBatch:
         if self._host is None:
             import jax
 
-            t0 = time.perf_counter()
-            raw = jax.device_get(self.out)
-            scal = raw["scal"]
-            host = {name: scal[:, i] for i, name in enumerate(_SCAL_COLS)}
-            host["bneck_kind_s"] = scal[:, _KIND_START:_KIND_STOP]
-            host["top_bneck_pe"] = scal[:, _TOP_PE_COL]
-            host["top_bneck_mem"] = scal[:, _TOP_MEM_COL]
-            s_busy, n_noc = self.dims
-            f = _N_FIXED_SCAL
-            host["pe_bneck_s"] = scal[:, f:f + s_busy]
-            host["mem_bneck_s"] = scal[:, f + s_busy:f + 2 * s_busy]
-            host["noc_bneck_s"] = scal[:, f + 2 * s_busy:f + 2 * s_busy + n_noc]
-            host["finish_s"] = raw["finish_s"]
-            host["bneck_code"] = raw["bneck_code"]
-            # non-finite guard accounting: a NaN/Inf fitness row is the
-            # device-side symptom the serve layer must never accept (real
-            # rows only — the pow2 pad rows replicate row 0)
-            fit = host["fitness"][: len(self.eds)]
-            bad = int(np.size(fit) - np.count_nonzero(np.isfinite(fit)))
-            if bad:
-                self.stats.n_nonfinite_rows += bad
-            self._host = host
+            with span("backend.fetch_wait", stats=self.stats, field="fetch_wait_s"):
+                raw = jax.device_get(self.out)
             self.consumed = True
-            self.stats.decode_s += time.perf_counter() - t0
+            with span("backend.decode", stats=self.stats, field="decode_s"):
+                self._host = self._unpack(raw)
         return self._host
+
+    def _unpack(self, raw: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+        """The fetched outputs as the standard output keys (zero-copy
+        column views), with the batch's non-finite rows counted."""
+        scal = raw["scal"]
+        host = {name: scal[:, i] for i, name in enumerate(_SCAL_COLS)}
+        host["bneck_kind_s"] = scal[:, _KIND_START:_KIND_STOP]
+        host["top_bneck_pe"] = scal[:, _TOP_PE_COL]
+        host["top_bneck_mem"] = scal[:, _TOP_MEM_COL]
+        s_busy, n_noc = self.dims
+        f = _N_FIXED_SCAL
+        host["pe_bneck_s"] = scal[:, f:f + s_busy]
+        host["mem_bneck_s"] = scal[:, f + s_busy:f + 2 * s_busy]
+        host["noc_bneck_s"] = scal[:, f + 2 * s_busy:f + 2 * s_busy + n_noc]
+        host["finish_s"] = raw["finish_s"]
+        host["bneck_code"] = raw["bneck_code"]
+        # non-finite guard accounting: a NaN/Inf fitness row is the
+        # device-side symptom the serve layer must never accept (real
+        # rows only — the pow2 pad rows replicate row 0)
+        fit = host["fitness"][: len(self.eds)]
+        bad = int(np.size(fit) - np.count_nonzero(np.isfinite(fit)))
+        if bad:
+            self.stats.n_nonfinite_rows += bad
+        return host
 
     def fitness(self) -> np.ndarray:
         return self.host()["fitness"]
@@ -685,10 +688,10 @@ class _JaxHandle:
 
     def result(self) -> SimResult:
         if self._res is None:
-            t0 = time.perf_counter()
-            with self._cand.materialized(self._backend.tdg) as design:
-                self._res = self._decode_against(design)
-            self._batch.stats.decode_s += time.perf_counter() - t0
+            self._batch.host()  # a first fetch counts as fetch_wait_s
+            with span("backend.decode", stats=self._batch.stats, field="decode_s"):
+                with self._cand.materialized(self._backend.tdg) as design:
+                    self._res = self._decode_against(design)
         return self._res
 
     def result_for(self, design: Design) -> SimResult:
@@ -696,10 +699,9 @@ class _JaxHandle:
         explorer's best-design snapshot, long after the candidate's base
         moved on). Bypasses — and does not populate — the memoized
         ``result()``."""
-        t0 = time.perf_counter()
-        res = self._decode_against(design)
-        self._batch.stats.decode_s += time.perf_counter() - t0
-        return res
+        self._batch.host()
+        with span("backend.decode", stats=self._batch.stats, field="decode_s"):
+            return self._decode_against(design)
 
     def _decode_against(self, design: Design) -> SimResult:
         out, j = self._batch.host(), self._j
@@ -718,10 +720,9 @@ class _JaxHandle:
         )
 
     def telemetry(self) -> SimTelemetry:
-        t0 = time.perf_counter()
-        tel = SimTelemetry.of_row(self._batch, self._j, self._cand, self._backend)
-        self._batch.stats.decode_s += time.perf_counter() - t0
-        return tel
+        self._batch.host()
+        with span("backend.decode", stats=self._batch.stats, field="decode_s"):
+            return SimTelemetry.of_row(self._batch, self._j, self._cand, self._backend)
 
 
 class JaxBatchedBackend:
@@ -890,18 +891,17 @@ class JaxBatchedBackend:
         runs K accept/reject iterations for R chains. Counted in the backend
         stats as R·K simulated designs in one dispatch."""
         runner = self.chain_runner()
-        t0 = time.perf_counter()
-        res = runner.run_chains(
-            req.design, req.budget, r=req.r, k=req.k, seed=req.seed,
-            it0=req.it0, menu=req.menu, alpha=req.alpha,
-            temperature0=req.temperature0, temp_decay=req.temp_decay,
-            taboo_ttl=req.taboo_ttl, carry=req.carry, alloc=req.alloc,
-            cap_pe=req.cap_pe, cap_mem=req.cap_mem,
-        )
+        with span("backend.run_chains", stats=self._stats, field="wall_s"):
+            res = runner.run_chains(
+                req.design, req.budget, r=req.r, k=req.k, seed=req.seed,
+                it0=req.it0, menu=req.menu, alpha=req.alpha,
+                temperature0=req.temperature0, temp_decay=req.temp_decay,
+                taboo_ttl=req.taboo_ttl, carry=req.carry, alloc=req.alloc,
+                cap_pe=req.cap_pe, cap_mem=req.cap_mem,
+            )
         self._stats.n_sims += req.r * req.k
         self._stats.n_batched += req.r * req.k
         self._stats.n_dispatches += 1
-        self._stats.wall_s += time.perf_counter() - t0
         return res
 
     def adopt_encoding(self, handle: SimHandle) -> None:
@@ -933,11 +933,9 @@ class JaxBatchedBackend:
         self._adopted[id(cand.base)] = (cand.base, ed)
 
     def _track_inflight(self, batch: _JaxBatch) -> None:
-        # in-flight = dispatched, not yet consumed by the host. The device
-        # may already have finished — the overlap claim is about SUBMISSION
-        # overlapping an un-consumed predecessor, which is what hides host
-        # encode behind device scoring, so readiness does not retire a batch
-        # from the depth metric while the list stays short. Abandoned
+        # in-flight = dispatched, not yet consumed by the host (the list
+        # flush() drains); readiness does not retire a batch while the list
+        # stays short. Abandoned
         # batches (a failed session's) are never consumed; to bound the
         # list WITHOUT voiding the flush() drain guarantee, overflow first
         # sheds batches whose compute already finished (nothing left to
@@ -958,9 +956,6 @@ class JaxBatchedBackend:
             alive = still[-7:]
         self._inflight = alive
         self._inflight.append(batch)
-        self._stats.n_inflight_max = max(
-            self._stats.n_inflight_max, len(self._inflight)
-        )
 
     def _fn(self):
         if self._jit is None:
@@ -1021,22 +1016,21 @@ class JaxBatchedBackend:
         return [h.result() for h in handles]
 
     def evaluate_candidates(self, cands: Sequence[Candidate]) -> List[SimHandle]:
-        t0 = time.perf_counter()
-        results: List[Optional[SimHandle]] = [None] * len(cands)
-        fast = [i for i, c in enumerate(cands) if c.vectorizable()]
-        fast_set = set(fast)
-        for i, c in enumerate(cands):
-            if i not in fast_set:
-                with c.materialized(self.tdg) as d:
-                    res = simulate(d, self.tdg, self.db)
-                results[i] = _ReadyHandle(res, _host_fitness(res, c), c, self.tdg)
-                self._stats.n_fallback += 1
-                self._note_bypass()
-        if fast:
-            self._evaluate_batch([cands[i] for i in fast], fast, results)
+        with span("backend.candidates", stats=self._stats, field="wall_s"):
+            results: List[Optional[SimHandle]] = [None] * len(cands)
+            fast = [i for i, c in enumerate(cands) if c.vectorizable()]
+            fast_set = set(fast)
+            for i, c in enumerate(cands):
+                if i not in fast_set:
+                    with c.materialized(self.tdg) as d:
+                        res = simulate(d, self.tdg, self.db)
+                    results[i] = _ReadyHandle(res, _host_fitness(res, c), c, self.tdg)
+                    self._stats.n_fallback += 1
+                    self._note_bypass()
+            if fast:
+                self._evaluate_batch([cands[i] for i in fast], fast, results)
         self._stats.n_sims += len(cands)
         self._stats.n_dispatches += 1
-        self._stats.wall_s += time.perf_counter() - t0
         return results  # type: ignore[return-value]
 
     def _evaluate_batch(
@@ -1047,195 +1041,165 @@ class JaxBatchedBackend:
             apply_delta, fill_budget, fill_row, fill_row_fields,
         )
 
-        tE = time.perf_counter()
-        # incremental encoding: each distinct base design is encoded once per
-        # dispatch (candidates of one explorer iteration share their base),
-        # then every candidate is the base row plus its recorded move delta.
-        # apply_delta is copy-on-write, so `ed.f is base.f` marks untouched
-        # fields — the buffer fill below broadcasts the base row per group
-        # and rewrites only what each move changed.
-        base_encs: Dict[int, EncodedDesign] = {}
-        eds: List[EncodedDesign] = []
-        keep: List[int] = []
-        # content-addressed cache bookkeeping (store attached): per-row cache
-        # keys to register after dispatch, same-dispatch alias rows, and the
-        # batch-local key → row map that dedupes identical candidates two
-        # co-batched sessions submit in one scheduler tick
-        store = self._store
-        row_keys: List[bytes] = []
-        aliases: List[tuple] = []  # (results index, dispatched row, Candidate, ed)
-        batch_rows: Dict[bytes, int] = {}
-        bud_digests: Dict[tuple, bytes] = {}
-        for pos, c in enumerate(batch):
-            key = id(c.base)
-            try:
-                ed = base_encs.get(key)
-                if ed is None:
-                    # adopted encodings first: the explorer promotes the
-                    # accepted winner's delta-encoding (bit-identical to a
-                    # from-scratch encode of the mutated design), so steady-
-                    # state dispatches never re-walk the base design's
-                    # object graph at all
-                    adopted = self._adopted.get(key)
-                    if adopted is not None and adopted[0] is c.base:
-                        ed = adopted[1]
-                    else:
-                        ed = EncodedDesign.of(c.base, self.tdg, self.db, self._enc)
-                    base_encs[key] = ed
-                if c.spec is not None:
-                    ed = apply_delta(ed, c.delta, c.base, self.tdg, self.db, self._enc)
-            except UnsupportedDesignError:
-                # the typed capability check: shapes the encoding cannot
-                # host route to the exact scalar path, mid-batch
-                with c.materialized(self.tdg) as d:
-                    res = simulate(d, self.tdg, self.db)
-                results[idx[pos]] = _ReadyHandle(
-                    res, _host_fitness(res, c), c, self.tdg
+        with span("backend.encode", stats=self._stats, field="encode_s"):
+            # incremental encoding: each distinct base design is encoded once per
+            # dispatch (candidates of one explorer iteration share their base),
+            # then every candidate is the base row plus its recorded move delta.
+            # apply_delta is copy-on-write, so `ed.f is base.f` marks untouched
+            # fields — the buffer fill below broadcasts the base row per group
+            # and rewrites only what each move changed.
+            base_encs: Dict[int, EncodedDesign] = {}
+            eds: List[EncodedDesign] = []
+            keep: List[int] = []
+            # content-addressed cache bookkeeping (store attached): per-row cache
+            # keys to register after dispatch, same-dispatch alias rows, and the
+            # batch-local key → row map that dedupes identical candidates two
+            # co-batched sessions submit in one scheduler tick
+            store = self._store
+            row_keys: List[bytes] = []
+            aliases: List[tuple] = []  # (results index, dispatched row, Candidate, ed)
+            batch_rows: Dict[bytes, int] = {}
+            bud_digests: Dict[tuple, bytes] = {}
+            for pos, c in enumerate(batch):
+                key = id(c.base)
+                try:
+                    ed = base_encs.get(key)
+                    if ed is None:
+                        # adopted encodings first: the explorer promotes the
+                        # accepted winner's delta-encoding (bit-identical to a
+                        # from-scratch encode of the mutated design), so steady-
+                        # state dispatches never re-walk the base design's
+                        # object graph at all
+                        adopted = self._adopted.get(key)
+                        if adopted is not None and adopted[0] is c.base:
+                            ed = adopted[1]
+                        else:
+                            ed = EncodedDesign.of(c.base, self.tdg, self.db, self._enc)
+                        base_encs[key] = ed
+                    if c.spec is not None:
+                        ed = apply_delta(ed, c.delta, c.base, self.tdg, self.db, self._enc)
+                except UnsupportedDesignError:
+                    # the typed capability check: shapes the encoding cannot
+                    # host route to the exact scalar path, mid-batch
+                    with c.materialized(self.tdg) as d:
+                        res = simulate(d, self.tdg, self.db)
+                    results[idx[pos]] = _ReadyHandle(
+                        res, _host_fitness(res, c), c, self.tdg
+                    )
+                    self._stats.n_fallback += 1
+                    self._note_bypass()
+                    continue
+                if store is not None:
+                    bkey = (id(c.budget), c.alpha)
+                    bud_dig = bud_digests.get(bkey)
+                    if bud_dig is None:
+                        bud_dig = bud_digests[bkey] = store.budget_digest(
+                            c.budget, c.alpha
+                        )
+                    ckey = store.key_of(ed, self._wl_digest, bud_dig)
+                    row = store.lookup(ckey)
+                    if row is not None:
+                        # store hit: serve from the memoized row of an earlier
+                        # identical evaluation — no device row dispatched. The
+                        # consumer's own encoding rides along for adoption.
+                        results[idx[pos]] = _JaxHandle(
+                            _CachedBatch(row, self._stats, ed), 0, c, self
+                        )
+                        self._stats.n_cache_hits += 1
+                        continue
+                    dup = batch_rows.get(ckey)
+                    if dup is not None:
+                        # same-dispatch alias: an identical candidate is already
+                        # in this batch — share its row instead of paying one
+                        # (the consumer's own ed rides along for adoption)
+                        aliases.append((idx[pos], dup, c, ed))
+                        self._stats.n_cache_hits += 1
+                        store.note_alias_hit()
+                        continue
+                    batch_rows[ckey] = len(eds)
+                    row_keys.append(ckey)
+                keep.append(pos)
+                eds.append(ed)
+            if len(keep) != len(batch):
+                batch = [batch[p] for p in keep]
+                idx = [idx[p] for p in keep]
+                if not batch:
+                    return
+
+            # pad slots and batch to power-of-two buckets: the jit cache then sees
+            # a handful of shapes over a whole exploration instead of one per
+            # block-count the moves walk through. Slot counts are bounded by the
+            # task count (moves allocate at most ~one block per task), so pinning
+            # the shared PE/MEM slot bucket at pow2(T) collapses that shape axis
+            # to one entry per workload; only the batch axis still varies. The
+            # NoC-chain axis buckets to pow2 WITHOUT a floor: the dominant
+            # single-NoC regime stays at N = 1 (compiling to exactly the
+            # historic kernel), and topology-heavy searches add at most
+            # log2(MAX_NOC) shapes.
+            # bucket over the candidate encodings AND their bases: the group
+            # fill broadcasts each base row before applying diffs, so a batch of
+            # all-join candidates (one slot/NoC fewer than base) must still
+            # host the base's shape
+            all_encs = list(base_encs.values())
+            all_encs.extend(eds)
+            need = max(max(e.pe_peak.shape[0], e.mem_bw.shape[0]) for e in all_encs)
+            slots = _bucket(max(need, len(self._enc.names)))
+            n_noc = max(1, _pow2(max(e.noc_bw.shape[0] for e in all_encs)))
+            b = len(batch)
+            b_pad = _bucket(b)
+            key = (b_pad, slots, n_noc)
+            # double-buffered per bucket: the previous dispatch of this shape may
+            # still be reading its (possibly zero-copy-aliased) host buffer, so a
+            # fresh encode flips to the other one. Two in-flight batches per
+            # bucket suffice; anything deeper would flush first.
+            pair = self._buffers.get(key)
+            if pair is None:
+                pair = self._buffers[key] = [None, None]
+            sel = self._bufsel.get(key, 0)
+            self._bufsel[key] = 1 - sel
+            rows = pair[sel]
+            if rows is None:
+                rows = pair[sel] = alloc_rows(
+                    b_pad, len(self._enc.names), slots, slots,
+                    len(self._enc.wl_names), n_noc,
                 )
-                self._stats.n_fallback += 1
-                self._note_bypass()
-                continue
-            if store is not None:
-                bkey = (id(c.budget), c.alpha)
-                bud_dig = bud_digests.get(bkey)
-                if bud_dig is None:
-                    bud_dig = bud_digests[bkey] = store.budget_digest(
-                        c.budget, c.alpha
-                    )
-                ckey = store.key_of(ed, self._wl_digest, bud_dig)
-                row = store.lookup(ckey)
-                if row is not None:
-                    # store hit: serve from the memoized row of an earlier
-                    # identical evaluation — no device row dispatched. The
-                    # consumer's own encoding rides along for adoption.
-                    results[idx[pos]] = _JaxHandle(
-                        _CachedBatch(row, self._stats, ed), 0, c, self
-                    )
-                    self._stats.n_cache_hits += 1
-                    continue
-                dup = batch_rows.get(ckey)
-                if dup is not None:
-                    # same-dispatch alias: an identical candidate is already
-                    # in this batch — share its row instead of paying one
-                    # (the consumer's own ed rides along for adoption)
-                    aliases.append((idx[pos], dup, c, ed))
-                    self._stats.n_cache_hits += 1
-                    store.note_alias_hit()
-                    continue
-                batch_rows[ckey] = len(eds)
-                row_keys.append(ckey)
-            keep.append(pos)
-            eds.append(ed)
-        if len(keep) != len(batch):
-            batch = [batch[p] for p in keep]
-            idx = [idx[p] for p in keep]
-            if not batch:
-                return
+            # reuse guard: two buffers cover two un-consumed dispatches per
+            # bucket, but the protocol lets callers keep MORE un-consumed. If
+            # the dispatch that last encoded into this slot might still be
+            # reading it (CPU XLA may alias the numpy buffer zero-copy), wait
+            # for its compute to finish before scribbling over its inputs.
+            owner = self._buf_owner.get((key, sel))
+            if owner is not None and not owner.consumed:
+                ready = getattr(owner.out["scal"], "is_ready", None)
+                if ready is None or not ready():
+                    import jax
 
-        # pad slots and batch to power-of-two buckets: the jit cache then sees
-        # a handful of shapes over a whole exploration instead of one per
-        # block-count the moves walk through. Slot counts are bounded by the
-        # task count (moves allocate at most ~one block per task), so pinning
-        # the shared PE/MEM slot bucket at pow2(T) collapses that shape axis
-        # to one entry per workload; only the batch axis still varies. The
-        # NoC-chain axis buckets to pow2 WITHOUT a floor: the dominant
-        # single-NoC regime stays at N = 1 (compiling to exactly the
-        # historic kernel), and topology-heavy searches add at most
-        # log2(MAX_NOC) shapes.
-        # bucket over the candidate encodings AND their bases: the group
-        # fill broadcasts each base row before applying diffs, so a batch of
-        # all-join candidates (one slot/NoC fewer than base) must still
-        # host the base's shape
-        all_encs = list(base_encs.values())
-        all_encs.extend(eds)
-        need = max(max(e.pe_peak.shape[0], e.mem_bw.shape[0]) for e in all_encs)
-        slots = _bucket(max(need, len(self._enc.names)))
-        n_noc = max(1, _pow2(max(e.noc_bw.shape[0] for e in all_encs)))
-        b = len(batch)
-        b_pad = _bucket(b)
-        key = (b_pad, slots, n_noc)
-        # double-buffered per bucket: the previous dispatch of this shape may
-        # still be reading its (possibly zero-copy-aliased) host buffer, so a
-        # fresh encode flips to the other one. Two in-flight batches per
-        # bucket suffice; anything deeper would flush first.
-        pair = self._buffers.get(key)
-        if pair is None:
-            pair = self._buffers[key] = [None, None]
-        sel = self._bufsel.get(key, 0)
-        self._bufsel[key] = 1 - sel
-        rows = pair[sel]
-        if rows is None:
-            rows = pair[sel] = alloc_rows(
-                b_pad, len(self._enc.names), slots, slots,
-                len(self._enc.wl_names), n_noc,
+                    jax.block_until_ready(owner.out["scal"])
+
+            # steady-state fast path (the explorer regime: one adopted base, one
+            # budget, full bucket): the buffer already holds base-row content
+            # everywhere except the cells last dispatch's diffs touched — restore
+            # just those from the base instead of refilling every row
+            bufkey = (key, sel)
+            prev = self._buf_state.get(bufkey)
+            c0 = batch[0]
+            uniform = all(
+                c.budget is c0.budget and c.alpha == c0.alpha for c in batch[1:]
             )
-        # reuse guard: two buffers cover two un-consumed dispatches per
-        # bucket, but the protocol lets callers keep MORE un-consumed. If
-        # the dispatch that last encoded into this slot might still be
-        # reading it (CPU XLA may alias the numpy buffer zero-copy), wait
-        # for its compute to finish before scribbling over its inputs.
-        owner = self._buf_owner.get((key, sel))
-        if owner is not None and not owner.consumed:
-            ready = getattr(owner.out["scal"], "is_ready", None)
-            if ready is None or not ready():
-                import jax
-
-                jax.block_until_ready(owner.out["scal"])
-
-        # steady-state fast path (the explorer regime: one adopted base, one
-        # budget, full bucket): the buffer already holds base-row content
-        # everywhere except the cells last dispatch's diffs touched — restore
-        # just those from the base instead of refilling every row
-        bufkey = (key, sel)
-        prev = self._buf_state.get(bufkey)
-        c0 = batch[0]
-        uniform = all(
-            c.budget is c0.budget and c.alpha == c0.alpha for c in batch[1:]
-        )
-        state0 = len(base_encs) == 1 and b == b_pad and uniform
-        fast = (
-            state0 and prev is not None
-            and prev[0] is base_encs[id(c0.base)]
-            and prev[1] is c0.budget
-            and prev[2] == c0.alpha
-        )
-        dirty: List[tuple] = []
-        if fast:
-            base_ed = prev[0]
-            for k, f in prev[3]:
-                fill_row_fields(rows, k, base_ed, (f,))
-            for k in range(b):
-                ed = eds[k]
-                if ed is not base_ed:
-                    changed = [
-                        f for f in ENCODED_FIELDS
-                        if getattr(ed, f) is not getattr(base_ed, f)
-                    ]
-                    fill_row_fields(rows, k, ed, changed)
-                    dirty.extend((k, f) for f in changed)
-            self._buf_state[bufkey] = (base_ed, c0.budget, c0.alpha, dirty)
-        else:
-            # fill per base-group: write the base encoding + budget once,
-            # broadcast across the group's rows, then apply per-candidate diffs
-            j = 0
-            while j < b:
-                cg = batch[j]
-                base_ed = base_encs[id(cg.base)]
-                end = j + 1
-                while end < b and batch[end].base is cg.base:
-                    end += 1
-                fill_row(rows, j, base_ed)
-                bud = cg.budget
-                if bud is not None:
-                    fill_budget(rows, j, self._enc, bud.latency_s, bud.power_w,
-                                bud.area_mm2, cg.alpha)
-                else:  # neutral scoring row (buffers are reused across dispatches)
-                    fill_budget(rows, j, self._enc, {}, 1e30, 1e30, 0.0)
-                if end - j > 1:
-                    for arr in rows.values():
-                        arr[j + 1:end] = arr[j]
-                for k in range(j, end):
-                    ed, c = eds[k], batch[k]
+            state0 = len(base_encs) == 1 and b == b_pad and uniform
+            fast = (
+                state0 and prev is not None
+                and prev[0] is base_encs[id(c0.base)]
+                and prev[1] is c0.budget
+                and prev[2] == c0.alpha
+            )
+            dirty: List[tuple] = []
+            if fast:
+                base_ed = prev[0]
+                for k, f in prev[3]:
+                    fill_row_fields(rows, k, base_ed, (f,))
+                for k in range(b):
+                    ed = eds[k]
                     if ed is not base_ed:
                         changed = [
                             f for f in ENCODED_FIELDS
@@ -1243,33 +1207,61 @@ class JaxBatchedBackend:
                         ]
                         fill_row_fields(rows, k, ed, changed)
                         dirty.extend((k, f) for f in changed)
-                    if k > j and c.budget is not bud:
-                        if c.budget is not None:
-                            fill_budget(rows, k, self._enc, c.budget.latency_s,
-                                        c.budget.power_w, c.budget.area_mm2, c.alpha)
-                        else:
-                            fill_budget(rows, k, self._enc, {}, 1e30, 1e30, 0.0)
-                j = end
-            if b < b_pad:  # pad the batch axis with copies of row 0
-                for arr in rows.values():
-                    arr[b:b_pad] = arr[0]
-            # the invariant the fast path needs: every row holds base+budget
-            # content except `dirty` — only true for single-group, uniform-
-            # budget, full-bucket dispatches
-            if state0:
-                self._buf_state[bufkey] = (
-                    base_encs[id(c0.base)], c0.budget, c0.alpha, dirty
-                )
+                self._buf_state[bufkey] = (base_ed, c0.budget, c0.alpha, dirty)
             else:
-                self._buf_state.pop(bufkey, None)
-        if key not in self._shapes:
-            self._shapes.add(key)
-            self._stats.n_compiles += 1
-        self._stats.encode_s += time.perf_counter() - tE
+                # fill per base-group: write the base encoding + budget once,
+                # broadcast across the group's rows, then apply per-candidate diffs
+                j = 0
+                while j < b:
+                    cg = batch[j]
+                    base_ed = base_encs[id(cg.base)]
+                    end = j + 1
+                    while end < b and batch[end].base is cg.base:
+                        end += 1
+                    fill_row(rows, j, base_ed)
+                    bud = cg.budget
+                    if bud is not None:
+                        fill_budget(rows, j, self._enc, bud.latency_s, bud.power_w,
+                                    bud.area_mm2, cg.alpha)
+                    else:  # neutral scoring row (buffers are reused across dispatches)
+                        fill_budget(rows, j, self._enc, {}, 1e30, 1e30, 0.0)
+                    if end - j > 1:
+                        for arr in rows.values():
+                            arr[j + 1:end] = arr[j]
+                    for k in range(j, end):
+                        ed, c = eds[k], batch[k]
+                        if ed is not base_ed:
+                            changed = [
+                                f for f in ENCODED_FIELDS
+                                if getattr(ed, f) is not getattr(base_ed, f)
+                            ]
+                            fill_row_fields(rows, k, ed, changed)
+                            dirty.extend((k, f) for f in changed)
+                        if k > j and c.budget is not bud:
+                            if c.budget is not None:
+                                fill_budget(rows, k, self._enc, c.budget.latency_s,
+                                            c.budget.power_w, c.budget.area_mm2, c.alpha)
+                            else:
+                                fill_budget(rows, k, self._enc, {}, 1e30, 1e30, 0.0)
+                    j = end
+                if b < b_pad:  # pad the batch axis with copies of row 0
+                    for arr in rows.values():
+                        arr[b:b_pad] = arr[0]
+                # the invariant the fast path needs: every row holds base+budget
+                # content except `dirty` — only true for single-group, uniform-
+                # budget, full-bucket dispatches
+                if state0:
+                    self._buf_state[bufkey] = (
+                        base_encs[id(c0.base)], c0.budget, c0.alpha, dirty
+                    )
+                else:
+                    self._buf_state.pop(bufkey, None)
+            if key not in self._shapes:
+                self._shapes.add(key)
+                self._stats.n_compiles += 1
 
-        tD = time.perf_counter()
-        out = self._fn()(rows)  # non-blocking: no host transfer here
-        self._stats.dispatch_s += time.perf_counter() - tD
+        with span("backend.dispatch", stats=self._stats, field="dispatch_s"):
+            out = self._fn()(rows)  # non-blocking: no host transfer here
         shared = _JaxBatch(out, self._stats, eds, (slots, n_noc))
         self._buf_owner[(key, sel)] = shared
         self._track_inflight(shared)

@@ -63,6 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime.spans import span
 from .blocks import FREQ_LADDER_MHZ
 from .budgets import Budget
 from .database import HardwareDatabase
@@ -766,142 +767,146 @@ class DeviceChainRunner:
             }
 
             def step(c: ChainCarry, it):
-                taboo = jnp.maximum(c.taboo - 1, 0)
-                keys = jax.vmap(lambda kk: jax.random.split(kk, 3))(c.key)
-                key, k_move, k_acc = keys[:, 0], keys[:, 1], keys[:, 2]
-                c = c._replace(key=key, taboo=taboo)
-                # ---- dynamic validity over the packed table -------------
-                a_task = jnp.clip(arg, 0, t - 1)
-                cur_pe = c.task_pe[:, a_task]  # (R, M)
-                cur_mem = c.task_mem[:, a_task]
-                a_pe = jnp.clip(arg, 0, cap_pe - 1)
-                a_mem = jnp.clip(arg, 0, cap_mem - 1)
-                d_pe = jnp.clip(dest, 0, cap_pe - 1)
-                d_mem = jnp.clip(dest, 0, cap_mem - 1)
-                load_pe = jnp.sum(
-                    c.task_pe[:, :, None]
-                    == jnp.arange(cap_pe)[None, None, :],
-                    axis=1,
-                )  # (R, cap_pe) tasks per slot
-                load_mem = jnp.sum(
-                    c.task_mem[:, :, None]
-                    == jnp.arange(cap_mem)[None, None, :],
-                    axis=1,
-                )
-                act_pe_d = c.pe_active[:, d_pe] > 0
-                act_mem_d = c.mem_active[:, d_mem] > 0
-                act_pe_a = c.pe_active[:, a_pe] > 0
-                act_mem_a = c.mem_active[:, a_mem] > 0
-                step_r = 2 * jnp.clip(dest, 0, 1) - 1
-                rung_pe = c.pe_rung[:, a_pe] + step_r
-                rung_mem = c.mem_rung[:, a_mem] + step_r
-                in_lad = lambda x: (x >= 0) & (x < _N_RUNG)
-                kd = kind[None, :]
-                valid = (
-                    ((kd == MV_MIG_PE) & (dest[None, :] != cur_pe) & act_pe_d)
-                    | ((kd == MV_MIG_MEM)
-                       & (dest[None, :] != cur_mem) & act_mem_d)
-                    | ((kd == MV_FORK_PE) & ~act_pe_d
-                       & (jnp.take_along_axis(load_pe, cur_pe, axis=1) >= 2))
-                    | ((kd == MV_FORK_MEM) & ~act_mem_d
-                       & (jnp.take_along_axis(load_mem, cur_mem, axis=1) >= 2))
-                    | ((kd == MV_JOIN_PE) & act_pe_a
-                       & (load_pe[:, a_pe] == 0))
-                    | ((kd == MV_JOIN_MEM) & act_mem_a
-                       & (load_mem[:, a_mem] == 0))
-                    | ((kd == MV_SWAP_PE) & act_pe_a & in_lad(rung_pe))
-                    | ((kd == MV_SWAP_MEM) & act_mem_a & in_lad(rung_mem))
-                    | ((kd == MV_ATT_PE) & act_pe_a
-                       & (dest[None, :] != c.pe_noc[:, a_pe]))
-                    | ((kd == MV_ATT_MEM) & act_mem_a
-                       & (dest[None, :] != c.mem_noc[:, a_mem]))
-                ) & (taboo == 0)
-                any_valid = jnp.any(valid, axis=1)  # (R,)
-                # ---- menu logits ----------------------------------------
-                if menu in ("telemetry", "farsi"):
-                    is_pe_cls = (kd % 2) == 0
-                    is_task_arg = kd <= MV_FORK_MEM
-                    w_task = jnp.where(
-                        is_pe_cls,
-                        jnp.take_along_axis(c.pe_bneck, cur_pe, axis=1),
-                        jnp.take_along_axis(c.mem_bneck, cur_mem, axis=1),
+                with jax.named_scope("chain.sample"):
+                    taboo = jnp.maximum(c.taboo - 1, 0)
+                    keys = jax.vmap(lambda kk: jax.random.split(kk, 3))(c.key)
+                    key, k_move, k_acc = keys[:, 0], keys[:, 1], keys[:, 2]
+                    c = c._replace(key=key, taboo=taboo)
+                    # ---- dynamic validity over the packed table -------------
+                    a_task = jnp.clip(arg, 0, t - 1)
+                    cur_pe = c.task_pe[:, a_task]  # (R, M)
+                    cur_mem = c.task_mem[:, a_task]
+                    a_pe = jnp.clip(arg, 0, cap_pe - 1)
+                    a_mem = jnp.clip(arg, 0, cap_mem - 1)
+                    d_pe = jnp.clip(dest, 0, cap_pe - 1)
+                    d_mem = jnp.clip(dest, 0, cap_mem - 1)
+                    load_pe = jnp.sum(
+                        c.task_pe[:, :, None]
+                        == jnp.arange(cap_pe)[None, None, :],
+                        axis=1,
+                    )  # (R, cap_pe) tasks per slot
+                    load_mem = jnp.sum(
+                        c.task_mem[:, :, None]
+                        == jnp.arange(cap_mem)[None, None, :],
+                        axis=1,
                     )
-                    w_slot = jnp.where(
-                        is_pe_cls, c.pe_bneck[:, a_pe], c.mem_bneck[:, a_mem]
+                    act_pe_d = c.pe_active[:, d_pe] > 0
+                    act_mem_d = c.mem_active[:, d_mem] > 0
+                    act_pe_a = c.pe_active[:, a_pe] > 0
+                    act_mem_a = c.mem_active[:, a_mem] > 0
+                    step_r = 2 * jnp.clip(dest, 0, 1) - 1
+                    rung_pe = c.pe_rung[:, a_pe] + step_r
+                    rung_mem = c.mem_rung[:, a_mem] + step_r
+                    in_lad = lambda x: (x >= 0) & (x < _N_RUNG)
+                    kd = kind[None, :]
+                    valid = (
+                        ((kd == MV_MIG_PE) & (dest[None, :] != cur_pe) & act_pe_d)
+                        | ((kd == MV_MIG_MEM)
+                           & (dest[None, :] != cur_mem) & act_mem_d)
+                        | ((kd == MV_FORK_PE) & ~act_pe_d
+                           & (jnp.take_along_axis(load_pe, cur_pe, axis=1) >= 2))
+                        | ((kd == MV_FORK_MEM) & ~act_mem_d
+                           & (jnp.take_along_axis(load_mem, cur_mem, axis=1) >= 2))
+                        | ((kd == MV_JOIN_PE) & act_pe_a
+                           & (load_pe[:, a_pe] == 0))
+                        | ((kd == MV_JOIN_MEM) & act_mem_a
+                           & (load_mem[:, a_mem] == 0))
+                        | ((kd == MV_SWAP_PE) & act_pe_a & in_lad(rung_pe))
+                        | ((kd == MV_SWAP_MEM) & act_mem_a & in_lad(rung_mem))
+                        | ((kd == MV_ATT_PE) & act_pe_a
+                           & (dest[None, :] != c.pe_noc[:, a_pe]))
+                        | ((kd == MV_ATT_MEM) & act_mem_a
+                           & (dest[None, :] != c.mem_noc[:, a_mem]))
+                    ) & (taboo == 0)
+                    any_valid = jnp.any(valid, axis=1)  # (R,)
+                    # ---- menu logits ----------------------------------------
+                    if menu in ("telemetry", "farsi"):
+                        is_pe_cls = (kd % 2) == 0
+                        is_task_arg = kd <= MV_FORK_MEM
+                        w_task = jnp.where(
+                            is_pe_cls,
+                            jnp.take_along_axis(c.pe_bneck, cur_pe, axis=1),
+                            jnp.take_along_axis(c.mem_bneck, cur_mem, axis=1),
+                        )
+                        w_slot = jnp.where(
+                            is_pe_cls, c.pe_bneck[:, a_pe], c.mem_bneck[:, a_mem]
+                        )
+                        w = jnp.where(is_task_arg, w_task, w_slot) + jnp.float32(
+                            1e-6
+                        )
+                        logw = jnp.log(w)
+                        if menu == "farsi":
+                            logw = logw + prec_log[kind][None, :]
+                    else:
+                        logw = jnp.zeros((r, kind.shape[0]), jnp.float32)
+                    logits = jnp.where(valid, logw, jnp.float32(-1e30))
+                    m = jax.vmap(jax.random.categorical)(k_move, logits)
+                # ---- apply the move, price the candidate platform -------
+                with jax.named_scope("chain.apply"):
+                    cand = apply_move(c, kind[m], arg[m], dest[m])
+                    rows = dict(rows_static)
+                    rows["task_pe"] = cand.task_pe
+                    rows["task_mem"] = cand.task_mem
+                    rows["pe_accel"] = jnp.take_along_axis(
+                        cand.accel, cand.task_pe[:, :, None], axis=2
+                    )[:, :, 0]
+                    for f in (
+                        "pe_peak", "pe_pj", "pe_leak", "pe_area", "pe_noc",
+                        "pe_active", "mem_bw", "mem_pj", "mem_leak",
+                        "mem_area_fixed", "mem_area_per_mb", "mem_noc",
+                        "mem_active",
+                    ):
+                        rows[f] = getattr(cand, f)
+                with jax.named_scope("chain.price"):
+                    res = resimulate_chains(
+                        enc, rows, use_kernel=use_kernel, interpret=interpret
                     )
-                    w = jnp.where(is_task_arg, w_task, w_slot) + jnp.float32(
-                        1e-6
-                    )
-                    logw = jnp.log(w)
-                    if menu == "farsi":
-                        logw = logw + prec_log[kind][None, :]
-                else:
-                    logw = jnp.zeros((r, kind.shape[0]), jnp.float32)
-                logits = jnp.where(valid, logw, jnp.float32(-1e30))
-                m = jax.vmap(jax.random.categorical)(k_move, logits)
-                # ---- apply + price the candidate platform ---------------
-                cand = apply_move(c, kind[m], arg[m], dest[m])
-                rows = dict(rows_static)
-                rows["task_pe"] = cand.task_pe
-                rows["task_mem"] = cand.task_mem
-                rows["pe_accel"] = jnp.take_along_axis(
-                    cand.accel, cand.task_pe[:, :, None], axis=2
-                )[:, :, 0]
-                for f in (
-                    "pe_peak", "pe_pj", "pe_leak", "pe_area", "pe_noc",
-                    "pe_active", "mem_bw", "mem_pj", "mem_leak",
-                    "mem_area_fixed", "mem_area_per_mb", "mem_noc",
-                    "mem_active",
-                ):
-                    rows[f] = getattr(cand, f)
-                res = resimulate_chains(
-                    enc, rows, use_kernel=use_kernel, interpret=interpret
-                )
-                f_new = res["fitness"].astype(jnp.float32)
-                # SA accept, f32 mirror of PolicyBase.accept; chains whose
-                # whole menu was masked (all-taboo / degenerate platform)
-                # force-reject and leave every state leaf untouched
-                temp = t0f * decayf ** it.astype(jnp.float32)
-                u = jax.vmap(
-                    lambda kk: jax.random.uniform(kk, dtype=jnp.float32)
-                )(k_acc)
-                ok = jnp.isfinite(f_new) & (
-                    (f_new < c.fitness)
-                    | (
-                        (temp > 0)
-                        & (
-                            u
-                            < jnp.exp(
-                                -(f_new - c.fitness)
-                                / jnp.maximum(temp, jnp.float32(1e-9))
+                    f_new = res["fitness"].astype(jnp.float32)
+                with jax.named_scope("chain.accept"):
+                    # SA accept, f32 mirror of PolicyBase.accept; chains whose
+                    # whole menu was masked (all-taboo / degenerate platform)
+                    # force-reject and leave every state leaf untouched
+                    temp = t0f * decayf ** it.astype(jnp.float32)
+                    u = jax.vmap(
+                        lambda kk: jax.random.uniform(kk, dtype=jnp.float32)
+                    )(k_acc)
+                    ok = jnp.isfinite(f_new) & (
+                        (f_new < c.fitness)
+                        | (
+                            (temp > 0)
+                            & (
+                                u
+                                < jnp.exp(
+                                    -(f_new - c.fitness)
+                                    / jnp.maximum(temp, jnp.float32(1e-9))
+                                )
                             )
                         )
                     )
-                )
-                ok = ok & any_valid
-                sel = lambda n, o: jnp.where(
-                    ok.reshape((r,) + (1,) * (o.ndim - 1)), n, o
-                )
-                merged = {
-                    f: sel(getattr(cand, f), getattr(c, f)) for f in _STATE
-                }
-                fit = jnp.where(ok, f_new, c.fitness)
-                tab_wr = taboo.at[ridx, m].set(jnp.int32(ttl))
-                taboo2 = jnp.where(
-                    (ok | ~any_valid)[:, None], taboo, tab_wr
-                )
-                pe_b = jnp.where(
-                    ok[:, None], res["pe_bneck_s"].astype(jnp.float32),
-                    c.pe_bneck,
-                )
-                mem_b = jnp.where(
-                    ok[:, None], res["mem_bneck_s"].astype(jnp.float32),
-                    c.mem_bneck,
-                )
-                c = c._replace(
-                    fitness=fit, taboo=taboo2, pe_bneck=pe_b, mem_bneck=mem_b,
-                    **merged,
-                )
+                    ok = ok & any_valid
+                    sel = lambda n, o: jnp.where(
+                        ok.reshape((r,) + (1,) * (o.ndim - 1)), n, o
+                    )
+                    merged = {
+                        f: sel(getattr(cand, f), getattr(c, f)) for f in _STATE
+                    }
+                    fit = jnp.where(ok, f_new, c.fitness)
+                    tab_wr = taboo.at[ridx, m].set(jnp.int32(ttl))
+                    taboo2 = jnp.where(
+                        (ok | ~any_valid)[:, None], taboo, tab_wr
+                    )
+                    pe_b = jnp.where(
+                        ok[:, None], res["pe_bneck_s"].astype(jnp.float32),
+                        c.pe_bneck,
+                    )
+                    mem_b = jnp.where(
+                        ok[:, None], res["mem_bneck_s"].astype(jnp.float32),
+                        c.mem_bneck,
+                    )
+                    c = c._replace(
+                        fitness=fit, taboo=taboo2, pe_bneck=pe_b, mem_bneck=mem_b,
+                        **merged,
+                    )
                 return c, (m.astype(jnp.int32), ok, fit)
 
             its = it0 + jnp.arange(k, dtype=jnp.int32)
@@ -956,33 +961,38 @@ class DeviceChainRunner:
         mapping-only table (bit-compatible sequences)."""
         if menu not in MENUS:
             raise ValueError(f"unknown device move menu: {menu!r}")
-        ed = EncodedDesign.of(design, self.g, self.db, self.enc)
-        cap_pe, cap_mem = self._capacities(ed, alloc, cap_pe, cap_mem, carry)
-        s_pe = int(ed.pe_peak.shape[0])
-        s_mem = int(ed.mem_bw.shape[0])
-        alloc = alloc or cap_pe > s_pe or cap_mem > s_mem
-        table = MoveTable.of(
-            ed, self.enc, alloc=alloc, cap_pe=cap_pe, cap_mem=cap_mem
-        )
-        row0 = self._row0(ed, budget, alpha)
-        fn = self._block(
-            r, k, ed, menu, temperature0, temp_decay, taboo_ttl, alloc,
-            cap_pe, cap_mem,
-        )
-        if carry is None:
-            carry = self.fresh_carry(
-                design, ed, r, seed, cap_pe=cap_pe, cap_mem=cap_mem,
-                alloc=alloc,
+        with span("chains.prep"):
+            ed = EncodedDesign.of(design, self.g, self.db, self.enc)
+            cap_pe, cap_mem = self._capacities(ed, alloc, cap_pe, cap_mem, carry)
+            s_pe = int(ed.pe_peak.shape[0])
+            s_mem = int(ed.mem_bw.shape[0])
+            alloc = alloc or cap_pe > s_pe or cap_mem > s_mem
+            table = MoveTable.of(
+                ed, self.enc, alloc=alloc, cap_pe=cap_pe, cap_mem=cap_mem
             )
-        elif not isinstance(carry, ChainCarry):
-            carry = ChainCarry(*carry)
+            row0 = self._row0(ed, budget, alpha)
+            fn = self._block(
+                r, k, ed, menu, temperature0, temp_decay, taboo_ttl, alloc,
+                cap_pe, cap_mem,
+            )
+            if carry is None:
+                carry = self.fresh_carry(
+                    design, ed, r, seed, cap_pe=cap_pe, cap_mem=cap_mem,
+                    alloc=alloc,
+                )
+            elif not isinstance(carry, ChainCarry):
+                carry = ChainCarry(*carry)
         t_start = time.perf_counter()
-        out_carry, (mv, acc, ft) = fn(
-            carry, jnp.int32(it0), row0,
-            table.kind, table.task, table.dest,
-        )
-        out_carry = ChainCarry(*(np.asarray(x) for x in out_carry))
-        mv, acc, ft = np.asarray(mv), np.asarray(acc), np.asarray(ft)
+        with span("chains.dispatch"):
+            out = fn(
+                carry, jnp.int32(it0), row0,
+                table.kind, table.task, table.dest,
+            )
+        with span("chains.wait"):
+            out_carry, (mv, acc, ft) = jax.block_until_ready(out)
+        with span("chains.readback"):
+            out_carry = ChainCarry(*(np.asarray(x) for x in out_carry))
+            mv, acc, ft = np.asarray(mv), np.asarray(acc), np.asarray(ft)
         wall = time.perf_counter() - t_start
         self.n_dispatches += 1
         self.n_chain_steps += r * k
