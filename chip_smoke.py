@@ -9,18 +9,21 @@ database. Four phases run in one process; the first wrong answer raises and
 ends the run with a non-zero exit:
 
   device      refuses any platform but TPU.
-  candidates  prices 64 single-NoC and 64 multi-NoC designs through
-              ``make_backend("jax")``, which must resolve to the Pallas
-              kernel compiled by Mosaic. The kernel agrees with the XLA
-              formulation on every output column, and the backend with the
+  candidates  prices 64 single-NoC and 64 multi-NoC designs through the
+              Pallas kernel compiled by Mosaic (``make_backend("pallas")``)
+              and through the default ``make_backend("jax")``, which must
+              resolve to the XLA formulation. The kernel agrees with the XLA
+              formulation on every output column, and each backend with the
               Python reference simulator on a sample.
   chains      a device-resident mixed mapping+allocation search (256 chains,
-              64 fused steps per block). Its winner re-prices on the Python
-              reference to the fitness the device reported, and the fused
-              block at R=1 replays the host-driven loop bit for bit.
+              64 fused steps per block) on the default backend. Its winner
+              re-prices on the Python reference to the fitness the device
+              reported, and the fused block at R=1 replays the host-driven
+              loop bit for bit.
   serve       16 sessions over two workloads and three policies, one of them
-              on device chains, six joining mid-flight. Every session ends
-              DONE; none failed, degraded or priced on the scalar fallback.
+              on device chains, six joining mid-flight, on the default
+              backend. Every session ends DONE; none failed, degraded or
+              priced on the scalar fallback.
 
 The seconds, compile counts and parity maxima printed along the way are
 readings of this one run, not metrics. The last line of standard output is
@@ -102,9 +105,10 @@ def rel_err(ref, got) -> float:
     return float(np.max(np.abs(a - b) / np.maximum(np.abs(a), 1e-12)))
 
 
-def expect_compiled_kernel(backend) -> None:
-    check(backend.name == "jax_pallas",
-          f"backend resolved to {backend.name!r}, not the Pallas kernel")
+def expect_backend(backend, name: str) -> None:
+    """``jax_pallas``: the Mosaic-compiled kernel; ``jax``: the XLA path."""
+    check(backend.name == name,
+          f"backend resolved to {backend.name!r}, not {name!r}")
     check(not backend.stats().kernel_interpret,
           "the Pallas kernel ran in interpret mode")
 
@@ -171,30 +175,36 @@ def phase_candidates(g, db, bud) -> None:
         check(worst <= KERNEL_TOL,
               f"{label}: kernel vs XLA relative error {worst:.3g} in {col}")
 
-        backend = make_backend("jax", g, db)
-        expect_compiled_kernel(backend)
-        handles = backend.evaluate_candidates(
-            [Candidate.of_design(d, bud, ALPHA) for d in designs]
-        )
-        fit = np.array([h.fitness for h in handles])
-        fit_err = rel_err(np.asarray(ref["fitness"]), fit)
-        check(fit_err <= KERNEL_TOL, f"{label}: backend vs XLA fitness {fit_err:.3g}")
-        lat_err = py_fit_err = 0.0
-        for j in range(0, len(designs), len(designs) // N_SAMPLE):
-            py = simulate(designs[j], g, db)
-            res = handles[j].result()
-            lat_err = max(lat_err, rel_err(py.latency_s, res.latency_s),
-                          rel_err([py.task_finish_s[t] for t in py.task_finish_s],
-                                  [res.task_finish_s[t] for t in py.task_finish_s]))
-            py_fit_err = max(py_fit_err, rel_err(
-                distance(py, bud).fitness(ALPHA), handles[j].fitness))
-        check(lat_err <= KERNEL_TOL, f"{label}: latency vs Python {lat_err:.3g}")
-        check(py_fit_err <= KERNEL_TOL, f"{label}: fitness vs Python {py_fit_err:.3g}")
-        st = backend.stats()
-        check(st.n_fallback == 0, f"{label}: {st.n_fallback} scalar fallbacks")
+        for name, resolved in (("pallas", "jax_pallas"), ("jax", "jax")):
+            backend = make_backend(name, g, db)
+            expect_backend(backend, resolved)
+            handles = backend.evaluate_candidates(
+                [Candidate.of_design(d, bud, ALPHA) for d in designs]
+            )
+            fit = np.array([h.fitness for h in handles])
+            fit_err = rel_err(np.asarray(ref["fitness"]), fit)
+            check(fit_err <= KERNEL_TOL,
+                  f"{label}: {name} backend vs XLA fitness {fit_err:.3g}")
+            lat_err = py_fit_err = 0.0
+            for j in range(0, len(designs), len(designs) // N_SAMPLE):
+                py = simulate(designs[j], g, db)
+                res = handles[j].result()
+                lat_err = max(lat_err, rel_err(py.latency_s, res.latency_s),
+                              rel_err([py.task_finish_s[t] for t in py.task_finish_s],
+                                      [res.task_finish_s[t] for t in py.task_finish_s]))
+                py_fit_err = max(py_fit_err, rel_err(
+                    distance(py, bud).fitness(ALPHA), handles[j].fitness))
+            check(lat_err <= KERNEL_TOL,
+                  f"{label}: {name} latency vs Python {lat_err:.3g}")
+            check(py_fit_err <= KERNEL_TOL,
+                  f"{label}: {name} fitness vs Python {py_fit_err:.3g}")
+            st = backend.stats()
+            check(st.n_fallback == 0,
+                  f"{label}: {name} {st.n_fallback} scalar fallbacks")
+            print(f"candidates[{label}, {name}]: vs Python latency {lat_err:.3g} "
+                  f"fitness {py_fit_err:.3g}", flush=True)
         print(f"candidates[{label}]: {len(designs)} designs, kernel vs XLA "
-              f"max rel {worst:.3g}, vs Python latency {lat_err:.3g} fitness "
-              f"{py_fit_err:.3g}, {time.perf_counter() - t0:.1f} s", flush=True)
+              f"max rel {worst:.3g}, {time.perf_counter() - t0:.1f} s", flush=True)
 
 
 def phase_chains(g, db, bud) -> None:
@@ -203,7 +213,7 @@ def phase_chains(g, db, bud) -> None:
         policy="farsi", backend="jax", chain_r=CHAIN_R, chain_k=CHAIN_K,
         chain_alloc=True,
     ))
-    expect_compiled_kernel(ex.backend)
+    expect_backend(ex.backend, "jax")
     res = ex.run_chains()
     dev_fit = res.history[-1]["fitness"]
     py_fit = distance(simulate(res.best_design, g, db), bud).fitness(ALPHA)
@@ -252,7 +262,7 @@ def phase_serve(db, bud) -> None:
     handles += [submit(i) for i in range(n_head, N_SESSIONS)]
     stats = svc.run()
     for be in svc.scheduler.backends().values():
-        expect_compiled_kernel(be)
+        expect_backend(be, "jax")
     not_done = [h.name for h in handles if not h.done]
     check(not not_done, f"serve: sessions not DONE: {not_done}")
     check(handles[0].result.chained, "serve: the chain session ran no chains")

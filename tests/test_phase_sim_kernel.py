@@ -105,3 +105,26 @@ def test_kernel_env_var_forces_kernel_path(monkeypatch):
     assert JaxBatchedBackend(g, db).name == "jax_pallas"
     monkeypatch.setenv("REPRO_PHASE_SIM_KERNEL", "0")
     assert JaxBatchedBackend(g, db).name == "jax"
+
+
+def test_default_backend_on_tpu_is_xla_path(monkeypatch):
+    """On TPU the default backend and its chain runner price through the XLA
+    path; the kernel is reached only by name or REPRO_PHASE_SIM_KERNEL=1."""
+    import jax
+
+    from repro.core import JaxBatchedBackend
+
+    db = HardwareDatabase()
+    g = audio()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("REPRO_PHASE_SIM_KERNEL", raising=False)
+    jb = JaxBatchedBackend(g, db)
+    assert jb.name == "jax"
+    assert jb.chain_runner().use_kernel is False
+    assert make_backend("jax", g, db).name == "jax"
+    pallas = make_backend("pallas", g, db)
+    assert pallas.name == "jax_pallas"
+    assert pallas.chain_runner().use_kernel is True
+    assert not pallas.stats().kernel_interpret
+    monkeypatch.setenv("REPRO_PHASE_SIM_KERNEL", "1")
+    assert JaxBatchedBackend(g, db).name == "jax_pallas"
