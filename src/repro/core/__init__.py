@@ -53,6 +53,7 @@ from .workloads import (
     cava,
     edge_detection,
     paper_budget,
+    pulse_doppler,
     synthetic_family,
 )
 
@@ -111,6 +112,7 @@ __all__ = [
     "make_accelerator",
     "make_backend",
     "make_policy",
+    "pulse_doppler",
     "synthetic_family",
     "make_gpp",
     "make_mem",

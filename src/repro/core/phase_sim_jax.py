@@ -570,29 +570,32 @@ def simulate_one(enc: EncodedWorkload, row: Dict[str, jnp.ndarray]) -> Dict[str,
     n_mem = row["mem_bw"].shape[-1]
     n_noc = row["noc_bw"].shape[-1]
     noc_bw = row["noc_bw"]
-    # loop-invariant hoists: effective peak rates per task and the
-    # same-slot co-residency masks behind Eq. 1/2 (PE share) and Eq. 4
-    # (burst-proportional memory share)
-    peak_eff = row["pe_peak"][task_pe] * row["pe_accel"]
-    mem_peak = row["mem_bw"][task_mem]
-    same_pe = (task_pe[:, None] == task_pe[None, :]).astype(jnp.float32)
-    same_mem = (task_mem[:, None] == task_mem[None, :]).astype(jnp.float32)
-    # one-hot task→slot maps: cap rollup and the per-slot bottleneck
-    # telemetry accumulate through these instead of segment_sum scatters
-    onehot_pe = (task_pe[:, None] == jnp.arange(n_pe)[None, :]).astype(jnp.float32)
-    onehot_mem = (task_mem[:, None] == jnp.arange(n_mem)[None, :]).astype(jnp.float32)
-    links = jnp.maximum(row["noc_links"], 1)  # (N,)
-    # multi-NoC chain routing: a task's route is the chain-index interval
-    # between its PE's and its MEM's NoC; hop count scales the NoC energy
-    pe_pos = row["pe_noc"][task_pe]
-    mem_pos = row["mem_noc"][task_mem]
-    lo = jnp.minimum(pe_pos, mem_pos)
-    hi = jnp.maximum(pe_pos, mem_pos)
-    hops = (hi - lo + 1).astype(jnp.float32)
-    nidx = jnp.arange(n_noc, dtype=jnp.int32)
-    on_route = (
-        (nidx[None, :] >= lo[:, None]) & (nidx[None, :] <= hi[:, None])
-    ).astype(jnp.float32)  # (T, N)
+    # the loop-invariant hoists and the phase loop are device scopes of
+    # their own (repro.runtime.spans), so a trace splits the two
+    with jax.named_scope("phase_sim.setup"):
+        # loop-invariant hoists: effective peak rates per task and the
+        # same-slot co-residency masks behind Eq. 1/2 (PE share) and Eq. 4
+        # (burst-proportional memory share)
+        peak_eff = row["pe_peak"][task_pe] * row["pe_accel"]
+        mem_peak = row["mem_bw"][task_mem]
+        same_pe = (task_pe[:, None] == task_pe[None, :]).astype(jnp.float32)
+        same_mem = (task_mem[:, None] == task_mem[None, :]).astype(jnp.float32)
+        # one-hot task→slot maps: cap rollup and the per-slot bottleneck
+        # telemetry accumulate through these instead of segment_sum scatters
+        onehot_pe = (task_pe[:, None] == jnp.arange(n_pe)[None, :]).astype(jnp.float32)
+        onehot_mem = (task_mem[:, None] == jnp.arange(n_mem)[None, :]).astype(jnp.float32)
+        links = jnp.maximum(row["noc_links"], 1)  # (N,)
+        # multi-NoC chain routing: a task's route is the chain-index interval
+        # between its PE's and its MEM's NoC; hop count scales the NoC energy
+        pe_pos = row["pe_noc"][task_pe]
+        mem_pos = row["mem_noc"][task_mem]
+        lo = jnp.minimum(pe_pos, mem_pos)
+        hi = jnp.maximum(pe_pos, mem_pos)
+        hops = (hi - lo + 1).astype(jnp.float32)
+        nidx = jnp.arange(n_noc, dtype=jnp.int32)
+        on_route = (
+            (nidx[None, :] >= lo[:, None]) & (nidx[None, :] <= hi[:, None])
+        ).astype(jnp.float32)  # (T, N)
 
     def noc_share(runf):
         """Eq. 3 per NoC: round-robin link striping (same link ⟺ running
@@ -736,9 +739,10 @@ def simulate_one(enc: EncodedWorkload, row: Dict[str, jnp.ndarray]) -> Dict[str,
         jnp.float32(0.0),
         jnp.int32(0),
     )
-    (rem_ops, rem_rd, rem_wr, completed, now, finish, bneck, bneck_noc,
-     kind_s, pe_bt, mem_bt, noc_bt, alp_t, traffic, nph) = jax.lax.fori_loop(
-        0, t, phase, state)
+    with jax.named_scope("phase_sim.phases"):
+        (rem_ops, rem_rd, rem_wr, completed, now, finish, bneck, bneck_noc,
+         kind_s, pe_bt, mem_bt, noc_bt, alp_t, traffic, nph) = jax.lax.fori_loop(
+            0, t, phase, state)
     # per-BLOCK bottleneck telemetry: phi attribution resolved to the
     # binding slot (task_pe for compute-bound, task_mem for memory-bound;
     # single-NoC chains resolve their one NoC column from kind_s[2])

@@ -15,6 +15,8 @@ AccelSeeker database evidently counts "ops" differently). We therefore keep
 the paper's latency budgets and latency *ratios*, and calibrate power/area
 budgets against our own database (``calibrated_budget``) so that convergence
 experiments are demanding but feasible — see EXPERIMENTS.md §Deviations.
+
+:func:`pulse_doppler` is a second domain: DS3's 449-task radar application.
 """
 from __future__ import annotations
 
@@ -117,6 +119,54 @@ def ar_complex() -> TaskGraph:
 
 
 PAPER_LATENCY_S = {"audio": 21e-3, "cava": 34e-3, "ed": 34e-3}
+
+
+def _fft_ops(n: int) -> float:
+    """An ``n``-point complex FFT: 5·n·log2 n operations."""
+    return 5.0 * n * (n.bit_length() - 1)
+
+
+def pulse_doppler(pulses: int = 128, range_bins: int = 256, doppler_tasks: int = 64) -> TaskGraph:
+    """DS3's pulse-Doppler radar reference application (Arda et al.,
+    arXiv:2003.09016; 449 tasks at the defaults), split along the textbook
+    chain: per pulse a range FFT, a multiply by the reference chirp's
+    spectrum and an IFFT (the matched filter); a corner turn into
+    ``doppler_tasks`` Doppler FFTs over ``range_bins / doppler_tasks`` range
+    bins each; one magnitude-and-threshold detector. Samples are complex
+    float32 (8 bytes); an N-point FFT counts 5·N·log2 N operations. Not in
+    :func:`all_workloads`: it is its own deployment."""
+    sample = 8.0  # bytes of one complex float32 sample
+    bins = range_bins // doppler_tasks
+    pulse_bytes = range_bins * sample
+    g = TaskGraph("pulse_doppler")
+
+    def add(name: str, ops: float, read_b: float, write_b: float, llp: float) -> None:
+        g.add_task(Task(name, work_ops=ops, i_read=ops / read_b, i_write=ops / write_b,
+                        llp=llp, burst_bytes=256))
+
+    fft_ops = _fft_ops(range_bins)
+    for i in range(pulses):
+        add(f"fft_p{i}", fft_ops, pulse_bytes, pulse_bytes, range_bins / 2)
+        # complex multiply, 6 ops a sample; reads the pulse and the chirp's spectrum
+        add(f"vmul_p{i}", 6.0 * range_bins, 2 * pulse_bytes, pulse_bytes, float(range_bins))
+        add(f"ifft_p{i}", fft_ops, pulse_bytes, pulse_bytes, range_bins / 2)
+    dfft_ops = bins * _fft_ops(pulses)
+    dfft_bytes = bins * pulses * sample
+    for j in range(doppler_tasks):
+        add(f"dfft_r{j}", dfft_ops, dfft_bytes, dfft_bytes, bins * pulses / 2)
+    cells = range_bins * pulses
+    # magnitude and threshold, 4 ops a cell; writes one detection byte a cell
+    add("detect", 4.0 * cells, cells * sample, float(cells), float(cells))
+    for i in range(pulses):
+        g.add_edge(f"fft_p{i}", f"vmul_p{i}", pulse_bytes)
+        g.add_edge(f"vmul_p{i}", f"ifft_p{i}", pulse_bytes)
+    for i in range(pulses):  # the corner turn: every pulse to every Doppler task
+        for j in range(doppler_tasks):
+            g.add_edge(f"ifft_p{i}", f"dfft_r{j}", bins * sample)
+    for j in range(doppler_tasks):
+        g.add_edge(f"dfft_r{j}", "detect", dfft_bytes)
+    g.validate()
+    return g
 
 
 def paper_budget() -> Budget:

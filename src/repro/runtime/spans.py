@@ -40,12 +40,17 @@ HOST_SPANS = (
     "backend.decode",  # decode_s: host decode of fetched outputs
 )
 
-# device scopes of one chain step (DeviceChainRunner._build_block)
+# device scopes of one chain step (DeviceChainRunner._build_block), then
+# those of the XLA phase simulator (phase_sim_jax.simulate_one), which nest
+# inside ``chain.price`` in a chain step; a reader that takes the first name
+# an operation's path holds still finds ``chain.price`` there
 DEVICE_SCOPES = (
     "chain.sample",  # move validity, menu logits, categorical draw
     "chain.apply",  # apply the drawn move, build the candidate's rows
     "chain.price",  # phase simulation of the candidate (kernel or XLA path)
     "chain.accept",  # SA accept and carry swap
+    "phase_sim.setup",  # loop-invariant hoists: co-residency masks, one-hots, routes
+    "phase_sim.phases",  # the phase loop
 )
 
 

@@ -161,6 +161,12 @@ def _block(g, db, d, bud, menu, alloc):
     return [res.move_idx, res.accepted, res.fit_trace, *res.carry], seen["text"]
 
 
+def _segment(scope):
+    """``scope`` as one segment of an operation's name path, bare or as a
+    transform names a scope opened inside it (``vmap(phase_sim.setup)``)."""
+    return re.compile(rf"/(\w+\()?{re.escape(scope)}\)?/")
+
+
 @pytest.mark.parametrize("menu,alloc", [("farsi", True), ("naive_sa", False)])
 def test_scopes_leave_the_chain_block_bit_identical(monkeypatch, menu, alloc):
     """An R=16 block traced with the device scopes and one traced with
@@ -178,5 +184,8 @@ def test_scopes_leave_the_chain_block_bit_identical(monkeypatch, menu, alloc):
     for a, b in zip(scoped, plain):
         assert a.dtype == b.dtype and np.array_equal(a, b)
     for scope in spans.DEVICE_SCOPES:
-        assert f"/{scope}/" in with_scopes, scope
-        assert f"/{scope}/" not in without, scope
+        assert _segment(scope).search(with_scopes), scope
+        assert not _segment(scope).search(without), scope
+    # the phase simulator's scopes nest inside the chain step's pricing
+    for scope in ("phase_sim.setup", "phase_sim.phases"):
+        assert f"/chain.price/vmap({scope})/" in with_scopes, scope
