@@ -24,10 +24,13 @@ Registered contracts:
                    count/order vs the :class:`MoveTable` row count and the
                    per-class capacity widths ``fresh_carry`` materializes
                    — the PR-9 bug class (taboo column narrower than the
-                   move table → silent modulo-aliasing of taboo TTLs).
+                   move table → silent modulo-aliasing of taboo TTLs) —
+                   and the table's rows vs the segment layout
+                   (``MoveTable.layout``) the fused block's menu is built
+                   from, row count and order.
 ``move-codes``     the ``MV_*`` code enumeration vs ``_KIND_PRECEDENCE``
-                   and the fused block's ``valid =`` dispatch expression —
-                   a new move kind must appear in all three.
+                   and the menu's per-kind validity dispatch (``_menu``)
+                   — a new move kind must appear in all three.
 ``policy-registry`` ``POLICIES`` vs per-class ``device_menu`` eligibility
                    vs both tables in ``docs/HEURISTICS.md``.
 """
@@ -49,6 +52,7 @@ __all__ = [
     "check_rollup_anchors",
     "check_chain_carry",
     "check_move_codes",
+    "check_move_layout",
     "check_policy_registry",
     "parse_md_tables",
 ]
@@ -228,6 +232,48 @@ def check_chain_carry(
     return out
 
 
+def check_move_layout(
+    segments: Sequence[Tuple[int, int, int]],
+    kind: Sequence[int],
+    arg: Sequence[int],
+    dest: Sequence[int],
+    menu_width: int,
+) -> List[str]:
+    """The packed table's rows are the layout's ``(kind, n_arg, n_dest)``
+    segments expanded in order (row ``a·n_dest + d`` of a segment has
+    ``arg = a``, ``dest = d``), and the menu built from that layout has one
+    column per row."""
+    out: List[str] = []
+    kind, arg, dest = list(kind), list(arg), list(dest)
+    off = 0
+    for k, n_a, n_d in segments:
+        n = n_a * n_d
+        want = (
+            [k] * n,
+            [a for a in range(n_a) for _ in range(n_d)],
+            list(range(n_d)) * n_a,
+        )
+        got = (kind[off:off + n], arg[off:off + n], dest[off:off + n])
+        if tuple(got) != want:
+            out.append(
+                f"MoveTable rows {off}..{off + n - 1} are not the layout's "
+                f"segment (kind {k}, {n_a} × {n_d}) — the fused block's "
+                "menu would score those rows as other moves"
+            )
+        off += n
+    if off != len(kind):
+        out.append(
+            f"MoveTable.layout covers {off} rows, the table has {len(kind)}"
+        )
+    if menu_width != len(kind):
+        out.append(
+            f"the fused block's menu has {menu_width} columns for "
+            f"{len(kind)} move-table rows — sampled row numbers would not "
+            "name table rows"
+        )
+    return out
+
+
 def check_move_codes(
     codes: Dict[str, int],
     precedence_len: int,
@@ -257,7 +303,7 @@ def check_move_codes(
     missing = sorted(set(codes) - set(dispatch_names))
     if missing:
         out.append(
-            f"the fused block's `valid =` dispatch never tests {missing!r}"
+            f"the fused block's menu (`_menu`) never tests {missing!r}"
             " — rows of that kind are unconditionally invalid (dead moves)"
         )
     return out
@@ -395,21 +441,15 @@ def kernel_rollup_width(src: str) -> Optional[int]:
 
 
 def dispatch_mv_names(src: str) -> List[str]:
-    """Every ``MV_*`` name referenced in the ``valid = …`` expression of
-    ``_build_block``'s step function."""
-    tree = ast.parse(src)
-    fn = _find_func(tree, "_build_block")
+    """Every ``MV_*`` name the fused block's menu (``_menu``, which builds
+    each kind's validity block) refers to."""
+    fn = _find_func(ast.parse(src), "_menu")
     if fn is None:
         return []
-    names: List[str] = []
-    for node in ast.walk(fn):
-        if isinstance(node, ast.Assign) and any(
-            isinstance(t, ast.Name) and t.id == "valid" for t in node.targets
-        ):
-            for sub in ast.walk(node.value):
-                if isinstance(sub, ast.Name) and sub.id.startswith("MV_"):
-                    names.append(sub.id)
-    return sorted(set(names))
+    return sorted({
+        sub.id for sub in ast.walk(fn)
+        if isinstance(sub, ast.Name) and sub.id.startswith("MV_")
+    })
 
 
 def state_tuple_fields(src: str) -> Optional[List[str]]:
@@ -498,6 +538,22 @@ def _carry_fixture():
     return runner, d, ed, cap_pe, cap_mem
 
 
+def _menu_width(carry, segments) -> int:
+    """Columns of the menu the fused block builds over ``segments`` for
+    ``carry``, traced abstractly."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.device_explore import _KIND_PRECEDENCE, ChainCarry, _menu
+
+    prec_log = jnp.log(jnp.asarray(_KIND_PRECEDENCE))
+    valid, _ = jax.eval_shape(
+        lambda c: _menu(ChainCarry(*c), segments, "farsi", prec_log),
+        tuple(carry),
+    )
+    return int(valid.shape[1])
+
+
 def _check_carry() -> List[Finding]:
     from repro.core.device_explore import ChainCarry, MoveTable
 
@@ -529,6 +585,14 @@ def _check_carry() -> List[Finding]:
             f"{len(ChainCarry._fields)}-field ChainCarry"
         )
     t = len(runner.enc.names)
+    # the layout _build_block derives from the block's static sizes
+    segments = MoveTable.layout(
+        t, cap_pe, cap_mem, int(ed.noc_bw.shape[0]), True
+    )
+    msgs.extend(check_move_layout(
+        segments, table.kind, table.task, table.dest,
+        _menu_width(carry, segments),
+    ))
     if tuple(carry.accel.shape) != (2, t, cap_pe):
         msgs.append(
             f"carry.accel shape {tuple(carry.accel.shape)} != (R, T, "
@@ -578,14 +642,15 @@ CONTRACTS: Tuple[Contract, ...] = (
     Contract(
         name="chain-carry",
         description="ChainCarry leaves ↔ MoveTable row count ↔ fresh_carry "
-        "widths ↔ _build_block._STATE coverage (PR-9 taboo-width class)",
+        "widths ↔ _build_block._STATE coverage (PR-9 taboo-width class) ↔ "
+        "MoveTable.layout segments the menu is built from",
         files=(F_DEVEXP,),
         check=_check_carry,
     ),
     Contract(
         name="move-codes",
-        description="MV_* enumeration ↔ _KIND_PRECEDENCE ↔ fused-block "
-        "validity dispatch",
+        description="MV_* enumeration ↔ _KIND_PRECEDENCE ↔ the menu's "
+        "per-kind validity dispatch",
         files=(F_DEVEXP,),
         check=_check_moves,
     ),

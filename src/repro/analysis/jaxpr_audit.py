@@ -180,7 +180,10 @@ def _audit_chain_block(runner, d, ed, bud) -> List[Finding]:
         d, ed, r=2, seed=0, cap_pe=cap_pe, cap_mem=cap_mem, alloc=True
     )
     row0 = runner._row0(ed, bud, 0.05)
-    fn = runner._build_block(2, 3, "farsi", 0.05, 0.997, 5, cap_pe, cap_mem)
+    fn = runner._build_block(
+        2, 3, "farsi", 0.05, 0.997, 5, cap_pe, cap_mem,
+        int(ed.noc_bw.shape[0]), True,
+    )
     jx = jax.make_jaxpr(fn)(
         carry, jnp.int32(0), row0, table.kind, table.task, table.dest
     )

@@ -340,10 +340,34 @@ class MoveTable:
     kind: np.ndarray  # (M,) int32 MV_* code
     task: np.ndarray  # (M,) int32 operand (task or slot index — see class)
     dest: np.ndarray  # (M,) int32 destination operand
+    segments: Tuple[Tuple[int, int, int], ...]  # see :meth:`layout`
 
     @property
     def n_moves(self) -> int:
         return int(self.kind.shape[0])
+
+    @staticmethod
+    def layout(
+        t: int, cap_pe: int, cap_mem: int, n_noc: int, alloc: bool
+    ) -> Tuple[Tuple[int, int, int], ...]:
+        """The table's row order, declared once: ``(kind, n_arg, n_dest)``
+        segments in row order, each the cross product of its operands —
+        segment row ``a·n_dest + d`` has ``arg = a``, ``dest = d``. Migrate
+        and fork cross the T tasks with the class's slots; join takes one
+        row per slot, swap two (ladder down/up), attach one per NoC chain
+        position (only when there are two or more)."""
+        segs = [(MV_MIG_PE, t, cap_pe), (MV_MIG_MEM, t, cap_mem)]
+        if alloc:
+            segs += [
+                (MV_FORK_PE, t, cap_pe), (MV_FORK_MEM, t, cap_mem),
+                (MV_JOIN_PE, cap_pe, 1), (MV_JOIN_MEM, cap_mem, 1),
+                (MV_SWAP_PE, cap_pe, 2), (MV_SWAP_MEM, cap_mem, 2),
+            ]
+            if n_noc > 1:
+                segs += [
+                    (MV_ATT_PE, cap_pe, n_noc), (MV_ATT_MEM, cap_mem, n_noc),
+                ]
+        return tuple(segs)
 
     @staticmethod
     def of(
@@ -359,47 +383,29 @@ class MoveTable:
         PR-8 table. ``alloc=True`` additionally enumerates fork/join/swap/
         NoC-attach rows over ``cap_pe``/``cap_mem`` padded slot inventories
         (default: pow2 ≥ real + 1, so at least one fork slot is free)."""
-        t = len(enc.names)
         s_pe = int(ed.pe_peak.shape[0])
         s_mem = int(ed.mem_bw.shape[0])
-        n_noc = int(ed.noc_bw.shape[0])
         if not alloc:
             cap_pe, cap_mem = s_pe, s_mem
         else:
             cap_pe = cap_pe or _pow2_at_least(s_pe + 1)
             cap_mem = cap_mem or _pow2_at_least(s_mem + 1)
-        kinds: List[np.ndarray] = []
-        args: List[np.ndarray] = []
-        dests: List[np.ndarray] = []
-        ti = np.arange(t, dtype=np.int32)
-
-        def rows(kind: int, arg: np.ndarray, dest: np.ndarray) -> None:
-            kinds.append(np.full(arg.shape[0], kind, np.int32))
-            args.append(arg.astype(np.int32))
-            dests.append(dest.astype(np.int32))
-
-        def cross(kind: int, a: np.ndarray, d: np.ndarray) -> None:
-            rows(kind, np.repeat(a, d.shape[0]), np.tile(d, a.shape[0]))
-
-        cross(MV_MIG_PE, ti, np.arange(cap_pe))
-        cross(MV_MIG_MEM, ti, np.arange(cap_mem))
-        if alloc:
-            si_pe = np.arange(cap_pe, dtype=np.int32)
-            si_mem = np.arange(cap_mem, dtype=np.int32)
-            updn = np.arange(2, dtype=np.int32)
-            cross(MV_FORK_PE, ti, si_pe)
-            cross(MV_FORK_MEM, ti, si_mem)
-            rows(MV_JOIN_PE, si_pe, np.zeros(cap_pe))
-            rows(MV_JOIN_MEM, si_mem, np.zeros(cap_mem))
-            cross(MV_SWAP_PE, si_pe, updn)
-            cross(MV_SWAP_MEM, si_mem, updn)
-            if n_noc > 1:
-                cross(MV_ATT_PE, si_pe, np.arange(n_noc))
-                cross(MV_ATT_MEM, si_mem, np.arange(n_noc))
+        segs = MoveTable.layout(
+            len(enc.names), cap_pe, cap_mem, int(ed.noc_bw.shape[0]), alloc
+        )
         return MoveTable(
-            kind=np.concatenate(kinds),
-            task=np.concatenate(args),
-            dest=np.concatenate(dests),
+            kind=np.concatenate(
+                [np.full(n_a * n_d, k, np.int32) for k, n_a, n_d in segs]
+            ),
+            task=np.concatenate(
+                [np.repeat(np.arange(n_a, dtype=np.int32), n_d)
+                 for _, n_a, n_d in segs]
+            ),
+            dest=np.concatenate(
+                [np.tile(np.arange(n_d, dtype=np.int32), n_a)
+                 for _, n_a, n_d in segs]
+            ),
+            segments=segs,
         )
 
     def delta_of(
@@ -419,6 +425,81 @@ class MoveTable:
             return mapping_delta({tname: inv[d]}, {})
         inv = {s: n for n, s in ed.mem_slot.items()}
         return mapping_delta({}, {tname: inv[d]})
+
+
+def _menu(
+    c: ChainCarry,
+    segments: Tuple[Tuple[int, int, int], ...],
+    menu: str,
+    prec_log: jnp.ndarray,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """One chain step's move menu: the (R, M) ``valid`` mask and sampling
+    ``logits`` of every :class:`MoveTable` row, for a carry whose ``taboo``
+    is the step's (already decremented).
+
+    No (R, M) gather: a row's operands are a task or a slot crossed with a
+    destination, so each segment of :meth:`MoveTable.layout` is a broadcast
+    of per-task (R, T) or per-slot (R, cap) quantities to its
+    (R, n_arg, n_dest) block, flattened and concatenated in row order. A
+    task's current slot's load and telemetry come through the (R, T, cap)
+    task→slot one-hot.
+
+    Valid rows: migrate a task to another active slot; fork it into an
+    inactive slot off a slot hosting ≥ 2 tasks; join an active empty slot;
+    swap an active slot one rung inside the ladder; attach an active slot
+    to another NoC position; never a taboo row. Log-weights: 0 for
+    ``naive_sa``; for ``telemetry`` log(bottleneck seconds + 1e-6) of the
+    row's focus slot (the task's current slot for migrate/fork, the operand
+    slot otherwise); ``farsi`` adds ``prec_log`` of the row's kind."""
+    r = c.fitness.shape[0]
+    eps = jnp.float32(1e-6)
+    classes = []  # [PE, MEM]: a kind code's class is its parity
+    for tm, act, rung, noc, bneck in (
+        (c.task_pe, c.pe_active, c.pe_rung, c.pe_noc, c.pe_bneck),
+        (c.task_mem, c.mem_active, c.mem_rung, c.mem_noc, c.mem_bneck),
+    ):
+        hot = tm[:, :, None] == jnp.arange(act.shape[1])  # (R, T, cap)
+        load = jnp.sum(hot, axis=1)  # (R, cap) tasks per slot
+        x = {
+            "slot": tm, "on": act > 0, "rung": rung, "noc": noc, "load": load,
+            # (R, T): the load of each task's current slot
+            "load_cur": jnp.sum(jnp.where(hot, load[:, None, :], 0), axis=2),
+        }
+        if menu != "naive_sa":
+            b_cur = jnp.sum(jnp.where(hot, bneck[:, None, :], 0.0), axis=2)
+            x["lw_task"] = jnp.log(b_cur + eps)
+            x["lw_slot"] = jnp.log(bneck + eps)
+        classes.append(x)
+    vs, lws = [], []
+    for kd, n_a, n_d in segments:
+        x = classes[kd % 2]
+        dd = jnp.arange(n_d, dtype=jnp.int32)
+        if kd in (MV_MIG_PE, MV_MIG_MEM):
+            v = (dd != x["slot"][:, :, None]) & x["on"][:, None, :]
+        elif kd in (MV_FORK_PE, MV_FORK_MEM):
+            v = ~x["on"][:, None, :] & (x["load_cur"] >= 2)[:, :, None]
+        elif kd in (MV_JOIN_PE, MV_JOIN_MEM):
+            v = (x["on"] & (x["load"] == 0))[:, :, None]
+        elif kd in (MV_SWAP_PE, MV_SWAP_MEM):
+            rung = x["rung"][:, :, None] + (2 * dd - 1)
+            v = x["on"][:, :, None] & (rung >= 0) & (rung < _N_RUNG)
+        elif kd in (MV_ATT_PE, MV_ATT_MEM):
+            v = x["on"][:, :, None] & (dd != x["noc"][:, :, None])
+        else:
+            raise ValueError(f"no validity rule for move kind {kd}")
+        flat = lambda a: jnp.broadcast_to(a, (r, n_a, n_d)).reshape(r, -1)
+        vs.append(flat(v))
+        if menu != "naive_sa":
+            lw = x["lw_task"] if kd <= MV_FORK_MEM else x["lw_slot"]
+            if menu == "farsi":
+                lw = lw + prec_log[kd]
+            lws.append(flat(lw[:, :, None]))
+    valid = jnp.concatenate(vs, axis=1) & (c.taboo == 0)
+    logw = (
+        jnp.concatenate(lws, axis=1) if lws
+        else jnp.zeros(valid.shape, jnp.float32)
+    )
+    return valid, jnp.where(valid, logw, jnp.float32(-1e30))
 
 
 @dataclasses.dataclass
@@ -637,7 +718,7 @@ class DeviceChainRunner:
         if fn is None:
             fn = self._build_block(
                 r, k, menu, float(t0), float(decay), int(ttl),
-                cap_pe, cap_mem,
+                cap_pe, cap_mem, int(ed.noc_bw.shape[0]), alloc,
             )
             self._blocks[key] = fn
             self.n_compiles += 1
@@ -645,7 +726,7 @@ class DeviceChainRunner:
 
     def _build_block(
         self, r: int, k: int, menu: str, t0: float, decay: float, ttl: int,
-        cap_pe: int, cap_mem: int,
+        cap_pe: int, cap_mem: int, n_noc: int, alloc: bool,
     ):
         # deferred: core must stay importable before kernels.phase_sim
         # finishes initializing (chain.py itself imports core.phase_sim_jax,
@@ -656,6 +737,7 @@ class DeviceChainRunner:
         enc = self.enc
         use_kernel, interpret = self.use_kernel, self.interpret
         t = len(enc.names)
+        segments = MoveTable.layout(t, cap_pe, cap_mem, n_noc, alloc)
         tidx = jnp.arange(t)
         ridx = jnp.arange(r)
         t0f, decayf = jnp.float32(t0), jnp.float32(decay)
@@ -772,74 +854,8 @@ class DeviceChainRunner:
                     keys = jax.vmap(lambda kk: jax.random.split(kk, 3))(c.key)
                     key, k_move, k_acc = keys[:, 0], keys[:, 1], keys[:, 2]
                     c = c._replace(key=key, taboo=taboo)
-                    # ---- dynamic validity over the packed table -------------
-                    a_task = jnp.clip(arg, 0, t - 1)
-                    cur_pe = c.task_pe[:, a_task]  # (R, M)
-                    cur_mem = c.task_mem[:, a_task]
-                    a_pe = jnp.clip(arg, 0, cap_pe - 1)
-                    a_mem = jnp.clip(arg, 0, cap_mem - 1)
-                    d_pe = jnp.clip(dest, 0, cap_pe - 1)
-                    d_mem = jnp.clip(dest, 0, cap_mem - 1)
-                    load_pe = jnp.sum(
-                        c.task_pe[:, :, None]
-                        == jnp.arange(cap_pe)[None, None, :],
-                        axis=1,
-                    )  # (R, cap_pe) tasks per slot
-                    load_mem = jnp.sum(
-                        c.task_mem[:, :, None]
-                        == jnp.arange(cap_mem)[None, None, :],
-                        axis=1,
-                    )
-                    act_pe_d = c.pe_active[:, d_pe] > 0
-                    act_mem_d = c.mem_active[:, d_mem] > 0
-                    act_pe_a = c.pe_active[:, a_pe] > 0
-                    act_mem_a = c.mem_active[:, a_mem] > 0
-                    step_r = 2 * jnp.clip(dest, 0, 1) - 1
-                    rung_pe = c.pe_rung[:, a_pe] + step_r
-                    rung_mem = c.mem_rung[:, a_mem] + step_r
-                    in_lad = lambda x: (x >= 0) & (x < _N_RUNG)
-                    kd = kind[None, :]
-                    valid = (
-                        ((kd == MV_MIG_PE) & (dest[None, :] != cur_pe) & act_pe_d)
-                        | ((kd == MV_MIG_MEM)
-                           & (dest[None, :] != cur_mem) & act_mem_d)
-                        | ((kd == MV_FORK_PE) & ~act_pe_d
-                           & (jnp.take_along_axis(load_pe, cur_pe, axis=1) >= 2))
-                        | ((kd == MV_FORK_MEM) & ~act_mem_d
-                           & (jnp.take_along_axis(load_mem, cur_mem, axis=1) >= 2))
-                        | ((kd == MV_JOIN_PE) & act_pe_a
-                           & (load_pe[:, a_pe] == 0))
-                        | ((kd == MV_JOIN_MEM) & act_mem_a
-                           & (load_mem[:, a_mem] == 0))
-                        | ((kd == MV_SWAP_PE) & act_pe_a & in_lad(rung_pe))
-                        | ((kd == MV_SWAP_MEM) & act_mem_a & in_lad(rung_mem))
-                        | ((kd == MV_ATT_PE) & act_pe_a
-                           & (dest[None, :] != c.pe_noc[:, a_pe]))
-                        | ((kd == MV_ATT_MEM) & act_mem_a
-                           & (dest[None, :] != c.mem_noc[:, a_mem]))
-                    ) & (taboo == 0)
+                    valid, logits = _menu(c, segments, menu, prec_log)
                     any_valid = jnp.any(valid, axis=1)  # (R,)
-                    # ---- menu logits ----------------------------------------
-                    if menu in ("telemetry", "farsi"):
-                        is_pe_cls = (kd % 2) == 0
-                        is_task_arg = kd <= MV_FORK_MEM
-                        w_task = jnp.where(
-                            is_pe_cls,
-                            jnp.take_along_axis(c.pe_bneck, cur_pe, axis=1),
-                            jnp.take_along_axis(c.mem_bneck, cur_mem, axis=1),
-                        )
-                        w_slot = jnp.where(
-                            is_pe_cls, c.pe_bneck[:, a_pe], c.mem_bneck[:, a_mem]
-                        )
-                        w = jnp.where(is_task_arg, w_task, w_slot) + jnp.float32(
-                            1e-6
-                        )
-                        logw = jnp.log(w)
-                        if menu == "farsi":
-                            logw = logw + prec_log[kind][None, :]
-                    else:
-                        logw = jnp.zeros((r, kind.shape[0]), jnp.float32)
-                    logits = jnp.where(valid, logw, jnp.float32(-1e30))
                     m = jax.vmap(jax.random.categorical)(k_move, logits)
                 # ---- apply the move, price the candidate platform -------
                 with jax.named_scope("chain.apply"):

@@ -18,6 +18,7 @@ from repro.analysis.contracts import (
     CARRY_PREFIX,
     check_chain_carry,
     check_move_codes,
+    check_move_layout,
     check_policy_registry,
     check_rollup_anchors,
     check_scal_cols,
@@ -361,6 +362,26 @@ def test_contract_move_codes_clean_and_trips():
     assert check_move_codes(codes, 3, list(codes))
     # dispatch forgets a kind
     assert check_move_codes(codes, 4, ["MV_MIG_PE", "MV_MIG_MEM"])
+
+
+def test_contract_move_layout_trips():
+    """The table's rows must be the layout's segments expanded in order,
+    and the menu must have one column per row."""
+    segs = ((0, 2, 3), (4, 3, 1))
+    kind = [0] * 6 + [4] * 3
+    arg = [0, 0, 0, 1, 1, 1, 0, 1, 2]
+    dest = [0, 1, 2, 0, 1, 2, 0, 0, 0]
+    assert not check_move_layout(segs, kind, arg, dest, 9)
+    # segments in another order than the rows
+    assert check_move_layout(segs[::-1], kind, arg, dest, 9)
+    # dest-major rows inside a segment
+    assert check_move_layout(
+        segs, kind, [0, 1, 0, 1, 0, 1, 0, 1, 2],
+        [0, 0, 1, 1, 2, 2, 0, 0, 0], 9,
+    )
+    # a row the layout does not cover, and a menu one column short
+    assert check_move_layout(segs, kind + [4], arg + [3], dest + [0], 10)
+    assert check_move_layout(segs, kind, arg, dest, 8)
 
 
 def test_contract_policy_registry_trips():
