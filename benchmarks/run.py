@@ -9,10 +9,10 @@ table. Prints ``name,us_per_call,derived`` CSV per row.
   fig14/15  budget relaxation vs system complexity/heterogeneity
   fig17     divide-and-conquer suboptimality
   roofline  all (arch × shape) baseline roofline terms
-  simbackend scalar-Python vs batched-JAX backend throughput, Pallas
-             kernel-vs-ref dispatch, explorer iteration rate incl. the
-             device-resident fused (R, K) chain blocks, heuristic-policy
-             convergence comparison + synthetic-scenario policy sweep
+  simbackend scalar-Python vs batched-JAX backend throughput, explorer
+             iteration rate incl. the device-resident fused (R, K) chain
+             blocks, heuristic-policy convergence comparison +
+             synthetic-scenario policy sweep
              (also writes BENCH_simbackend.json for trajectory tracking)
 
 After a full (non ``--smoke``) run, every ``benchmarks/BENCH_*.json`` is
@@ -85,9 +85,9 @@ def main() -> None:
         "tiny simbackend run that *asserts* the "
         "JAX neighbour-eval path beats the Python path, both agree on the "
         "winner, multi-NoC batches dispatch at ≥0.5x the single-NoC "
-        "throughput with zero fallbacks, the Pallas kernel matches the ref "
-        "path ≤1e-5, the fused device loop sustains ≥2x the host-driven "
-        "loop at R=16 (n_compiles ≤ 4, n_fallback == 0, R=1 parity), the "
+        "throughput with zero fallbacks, the fused device loop sustains "
+        "≥2x the host-driven loop at R=16 (n_compiles ≤ 4, n_fallback == "
+        "0, R=1 parity), the "
         "mixed mapping+allocation block does the same on the widened move "
         "table (R=1 parity, ≥2x at R=16, n_compiles ≤ 6, n_fallback == 0), "
         "the root BENCH-json mirror is byte-identical to its source, and "

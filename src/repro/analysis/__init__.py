@@ -4,7 +4,7 @@ Three passes that make the repo's recurring desync bugs un-shippable
 (``python -m repro.analysis --strict`` gates tier-1 and the bench smoke):
 
 * :mod:`~repro.analysis.contracts` — cross-file layout contracts
-  (scal-column schema, ChainCarry/MoveTable widths, MV_* dispatch
+  (ChainCarry/MoveTable widths, MV_* dispatch
   coverage, policy registry vs docs).
 * :mod:`~repro.analysis.lint` — AST rules over ``src/repro/`` for
   host-sync and retracing hazards inside traced scopes.

@@ -14,12 +14,6 @@ translate messages into :class:`~repro.analysis.findings.Finding` records.
 
 Registered contracts:
 
-``scal-cols``      ``core.scal_layout`` is the single source of truth for
-                   the packed scalar-column layout; the Pallas kernel's
-                   rollup stack, ``ops.py``'s re-export and the backend's
-                   fixed-column math must all agree with it (PR-4/PR-6
-                   desync class: a column added on one side only shifts
-                   every downstream telemetry read by one).
 ``chain-carry``    :class:`~repro.core.device_explore.ChainCarry` leaf
                    count/order vs the :class:`MoveTable` row count and the
                    per-class capacity widths ``fresh_carry`` materializes
@@ -48,8 +42,6 @@ __all__ = [
     "Contract",
     "CONTRACTS",
     "run_contracts",
-    "check_scal_cols",
-    "check_rollup_anchors",
     "check_chain_carry",
     "check_move_codes",
     "check_move_layout",
@@ -61,10 +53,6 @@ _REPO_SRC = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _REPO = os.path.dirname(_REPO_SRC)
 
-F_LAYOUT = "src/repro/core/scal_layout.py"
-F_KERNEL = "src/repro/kernels/phase_sim/kernel.py"
-F_OPS = "src/repro/kernels/phase_sim/ops.py"
-F_BACKEND = "src/repro/core/backend.py"
 F_DEVEXP = "src/repro/core/device_explore.py"
 F_POLICY = "src/repro/core/policy.py"
 F_HEUR = "docs/HEURISTICS.md"
@@ -98,82 +86,6 @@ def _src(rel: str) -> str:
 # ---------------------------------------------------------------------------
 # pure checks (unit-testable with injected, deliberately-desynced values)
 # ---------------------------------------------------------------------------
-def check_scal_cols(
-    layout_cols: Sequence[str],
-    kernel_cols: Sequence[str],
-    backend_prefix: Sequence[str],
-    backend_n_fixed: int,
-    rollup_width: Optional[int] = None,
-) -> List[str]:
-    out: List[str] = []
-    if tuple(kernel_cols) != tuple(layout_cols):
-        out.append(
-            "kernel.SCAL_COLS != scal_layout.SCAL_COLS: "
-            f"{tuple(kernel_cols)!r} vs {tuple(layout_cols)!r}"
-        )
-    if tuple(layout_cols[: len(backend_prefix)]) != tuple(backend_prefix):
-        out.append(
-            "backend._SCAL_COLS is not a prefix of the layout: "
-            f"{tuple(backend_prefix)!r} vs {tuple(layout_cols)!r}"
-        )
-    if backend_n_fixed != len(layout_cols):
-        out.append(
-            f"backend._N_FIXED_SCAL ({backend_n_fixed}) != "
-            f"len(SCAL_COLS) ({len(layout_cols)}) — every telemetry "
-            "column read after the fixed block shifts"
-        )
-    if rollup_width is not None and rollup_width != len(layout_cols):
-        out.append(
-            f"the kernel rollup stacks {rollup_width} scalars but "
-            f"SCAL_COLS names {len(layout_cols)} — the packed scal row "
-            "and its schema disagree"
-        )
-    return out
-
-
-# schema-name → source stem that must appear in the kernel rollup element
-# at the SAME index. The rollup is positional (a stack of local values, no
-# names), so name-diffing alone cannot catch a reordered schema — these
-# anchors tie the column name to the expression that computes it.
-# latency_s is deliberately unanchored (the kernel calls it `now`).
-ROLLUP_ANCHORS = {
-    "energy_j": "energy",
-    "power_w": "power",
-    "area_mm2": "area",
-    "fitness": "fitness",
-    "alp_time_s": "alp",
-    "traffic_bytes": "traffic",
-    "n_phases": "nph",
-    "all_done": "completed",
-    "kind_pe_s": "kind_s[0]",
-    "kind_mem_s": "kind_s[1]",
-    "kind_noc_s": "kind_s[2]",
-    "top_bneck_pe": "pe_b",
-    "top_bneck_mem": "mem_b",
-}
-
-
-def check_rollup_anchors(
-    layout_cols: Sequence[str], rollup_srcs: Optional[Sequence[str]]
-) -> List[str]:
-    """The kernel rollup element at each column's index must mention that
-    column's anchor stem — catches a reorder of the (single-sourced)
-    schema that the tautological name-diff cannot see."""
-    if rollup_srcs is None or len(rollup_srcs) != len(layout_cols):
-        return []  # width mismatch is already its own finding
-    out: List[str] = []
-    for i, col in enumerate(layout_cols):
-        stem = ROLLUP_ANCHORS.get(col)
-        if stem is not None and stem not in rollup_srcs[i]:
-            out.append(
-                f"SCAL_COLS[{i}] = {col!r} but the kernel rollup element "
-                f"there is `{rollup_srcs[i]}` (expected it to mention "
-                f"{stem!r}) — the schema and the kernel's positional "
-                "stack have desynced"
-            )
-    return out
-
-
 # the PR-8 mapping-only carry prefix: checkpoints and parity tests iterate
 # these leaves positionally, so their order is load-bearing
 CARRY_PREFIX = (
@@ -409,37 +321,6 @@ def _find_func(tree: ast.Module, name: str) -> Optional[ast.FunctionDef]:
     return None
 
 
-def kernel_rollup_sources(src: str) -> Optional[List[str]]:
-    """Source text of each element of the ``scal_ref[0] = jnp.stack([...])``
-    rollup in the Pallas kernel — the packed scal row, positionally."""
-    tree = ast.parse(src)
-    for node in ast.walk(tree):
-        if not (isinstance(node, ast.Assign) and len(node.targets) == 1):
-            continue
-        t = node.targets[0]
-        if not (
-            isinstance(t, ast.Subscript)
-            and isinstance(t.value, ast.Name)
-            and t.value.id == "scal_ref"
-        ):
-            continue
-        v = node.value
-        if (
-            isinstance(v, ast.Call)
-            and isinstance(v.func, ast.Attribute)
-            and v.func.attr == "stack"
-            and v.args
-            and isinstance(v.args[0], (ast.List, ast.Tuple))
-        ):
-            return [ast.unparse(e) for e in v.args[0].elts]
-    return None
-
-
-def kernel_rollup_width(src: str) -> Optional[int]:
-    srcs = kernel_rollup_sources(src)
-    return None if srcs is None else len(srcs)
-
-
 def dispatch_mv_names(src: str) -> List[str]:
     """Every ``MV_*`` name the fused block's menu (``_menu``, which builds
     each kind's validity block) refers to."""
@@ -482,41 +363,6 @@ def _msgs_to_findings(
                 path=path, related=related)
         for m in msgs
     ]
-
-
-def _check_scal() -> List[Finding]:
-    from repro.core import backend, scal_layout
-    from repro.kernels.phase_sim import kernel, ops
-
-    rollup = kernel_rollup_sources(_src(F_KERNEL))
-    msgs = check_scal_cols(
-        layout_cols=scal_layout.SCAL_COLS,
-        kernel_cols=kernel.SCAL_COLS,
-        backend_prefix=backend._SCAL_COLS,
-        backend_n_fixed=backend._N_FIXED_SCAL,
-        rollup_width=None if rollup is None else len(rollup),
-    )
-    if rollup is None:
-        msgs.append(
-            "could not locate the `scal_ref[0] = jnp.stack([...])` rollup "
-            "in the kernel — the scal-cols contract lost its anchor"
-        )
-    msgs.extend(check_rollup_anchors(scal_layout.SCAL_COLS, rollup))
-    if tuple(ops.SCAL_COLS) != tuple(scal_layout.SCAL_COLS):
-        msgs.append("ops.SCAL_COLS re-export drifted from the layout")
-    # the index constants must keep addressing what their names claim
-    if scal_layout.SCAL_COLS[scal_layout.KIND_START:scal_layout.KIND_STOP] \
-            != scal_layout.BNECK_KIND_COLS:
-        msgs.append("KIND_START/KIND_STOP no longer bracket the "
-                    "bneck-kind triple")
-    if (scal_layout.SCAL_COLS[scal_layout.TOP_PE_COL],
-            scal_layout.SCAL_COLS[scal_layout.TOP_MEM_COL]) \
-            != scal_layout.TOP_BNECK_COLS:
-        msgs.append("TOP_PE_COL/TOP_MEM_COL do not address the "
-                    "top-bottleneck pair")
-    return _msgs_to_findings(
-        msgs, "scal-cols", F_LAYOUT, (F_KERNEL, F_OPS, F_BACKEND)
-    )
 
 
 def _carry_fixture():
@@ -632,13 +478,6 @@ def _check_policies() -> List[Finding]:
 
 
 CONTRACTS: Tuple[Contract, ...] = (
-    Contract(
-        name="scal-cols",
-        description="packed scal-column layout: kernel rollup ↔ ops "
-        "re-export ↔ backend fixed-column math ↔ core.scal_layout",
-        files=(F_LAYOUT, F_KERNEL, F_OPS, F_BACKEND),
-        check=_check_scal,
-    ),
     Contract(
         name="chain-carry",
         description="ChainCarry leaves ↔ MoveTable row count ↔ fresh_carry "

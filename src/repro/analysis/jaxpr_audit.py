@@ -11,9 +11,6 @@ equation, recursing through sub-jaxprs (``pjit``, ``scan`` bodies,
     ``io_callback`` / debug callbacks) and host transfers
     (``infeed``/``outfeed``/``outside_call``) would turn the fused block
     into a per-step host round-trip while still "working";
-  * the Pallas wrapper really lowers through ``pallas_call`` (a silent
-    fallback to the vmap reference would pass every numeric test at 10×
-    the dispatch cost);
   * the **jit-cache key bound**: the backend buckets batch/slot shapes to
     pow2 (floor 4) exactly so the compile-cache key set stays small. The
     audit enumerates the documented production grid (batch and slots up
@@ -23,10 +20,9 @@ equation, recursing through sub-jaxprs (``pjit``, ``scan`` bodies,
     in a prod flamegraph.
 
 Entry points audited: ``phase_sim_jax.simulate_batch`` (the vmap'd
-scoring core), the fused chain block (``DeviceChainRunner._build_block``
-on the alloc menu — scan over K steps), and the Pallas wrapper
-``ops.phase_sim`` (``interpret=True`` so the audit runs on CPU-only
-hosts; the jaxpr is the same either way).
+scoring core) and the fused chain block
+(``DeviceChainRunner._build_block`` on the alloc menu — scan over K
+steps).
 """
 from __future__ import annotations
 
@@ -83,11 +79,10 @@ def audit_jaxpr(
     jaxpr,
     path: str,
     *,
-    require: Sequence[str] = (),
     forbidden: Sequence[str] = FORBIDDEN_SUBSTRINGS,
 ) -> List[Finding]:
-    """Findings for one traced entry point: forbidden primitives present,
-    or required ones (``pallas_call``) missing."""
+    """Findings for one traced entry point: the forbidden primitives it
+    lowers through."""
     prims = collect_primitives(jaxpr)
     out: List[Finding] = []
     for p in sorted(prims):
@@ -100,16 +95,6 @@ def audit_jaxpr(
                     path=path,
                 ))
                 break
-    for want in require:
-        if want not in prims:
-            out.append(Finding(
-                pass_name="jaxpr", rule="missing-primitive",
-                message=f"`{name}` no longer lowers through `{want}` "
-                "(primitives seen: "
-                f"{', '.join(sorted(prims)[:12])}…) — the kernel path "
-                "silently fell back",
-                path=path,
-            ))
     return out
 
 
@@ -193,21 +178,6 @@ def _audit_chain_block(runner, d, ed, bud) -> List[Finding]:
     )
 
 
-def _audit_pallas_wrapper(runner, ed, bud) -> List[Finding]:
-    import jax
-
-    from repro.kernels.phase_sim.ops import phase_sim
-
-    rows = _abstract_rows(runner.enc, ed, bud, 0.05, b=4)
-    jx = jax.make_jaxpr(lambda r: phase_sim(runner.enc, r, interpret=True))(
-        rows
-    )
-    return audit_jaxpr(
-        "ops.phase_sim", jx, "src/repro/kernels/phase_sim/ops.py",
-        require=("pallas_call",),
-    )
-
-
 def _audit_bucket_grid() -> List[Finding]:
     from repro.core.backend import _bucket
 
@@ -232,7 +202,7 @@ def _audit_bucket_grid() -> List[Finding]:
 
 def run_jaxpr_audit(entries: Optional[Sequence[str]] = None) -> List[Finding]:
     """Trace and audit all entry points (or a named subset of
-    ``{"simulate_batch", "chain_block", "pallas", "buckets"}``)."""
+    ``{"simulate_batch", "chain_block", "buckets"}``)."""
     want = set(entries) if entries is not None else None
     out: List[Finding] = []
 
@@ -241,13 +211,10 @@ def run_jaxpr_audit(entries: Optional[Sequence[str]] = None) -> List[Finding]:
 
     if on("buckets"):
         out.extend(_audit_bucket_grid())
-    if on("simulate_batch") or on("chain_block") or on("pallas") \
-            or want is None:
+    if on("simulate_batch") or on("chain_block"):
         runner, d, ed, bud = _fixture()
         if on("simulate_batch"):
             out.extend(_audit_simulate_batch(runner, ed, bud))
         if on("chain_block"):
             out.extend(_audit_chain_block(runner, d, ed, bud))
-        if on("pallas"):
-            out.extend(_audit_pallas_wrapper(runner, ed, bud))
     return out

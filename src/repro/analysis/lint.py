@@ -24,8 +24,9 @@ Scope detection — a function is considered **traced** when any of:
     called by the vmap lambda in ``simulate_batch``);
   * its ``def`` line carries the explicit marker comment
     ``# repro: traced`` — for functions whose tracing caller lives in a
-    *different* module (``ops.phase_sim`` is jitted by the backend), where
-    no static analysis of this file can see the jit.
+    *different* module (``chain.resimulate_chains`` is traced by the
+    chain block in ``core.device_explore``), where no static analysis of
+    this file can see the jit.
 
 Rules (ids are what ``# repro: noqa[<rule>]`` must name):
 

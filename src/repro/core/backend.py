@@ -49,14 +49,6 @@ from .database import HardwareDatabase
 from .design import Design
 from .moves import MoveDelta, MoveSpec, apply_spec
 from .phase_sim import SimResult, simulate
-from .scal_layout import (
-    KIND_START as _KIND_START,
-    KIND_STOP as _KIND_STOP,
-    N_SCAL as _N_FIXED_SCAL,
-    SCAL_PREFIX as _SCAL_COLS,
-    TOP_MEM_COL as _TOP_MEM_COL,
-    TOP_PE_COL as _TOP_PE_COL,
-)
 from .ppa import total_leakage_w
 from .tdg import TaskGraph, workload_of
 
@@ -95,9 +87,6 @@ class BackendStats:
     # the serve layer's non-finite guard rejects these; a nonzero count on a
     # healthy backend means a numerical escape worth investigating
     n_nonfinite_rows: int = 0
-    # the Pallas kernel runs in interpret mode (CPU only): it prices the
-    # same math through the host interpreter instead of a Mosaic launch
-    kernel_interpret: bool = False
     # total time inside the entries (spans backend.designs, backend.candidates,
     # backend.run_chains)
     wall_s: float = 0.0
@@ -552,18 +541,30 @@ def _bucket(n: int) -> int:
 # every per-design scalar into ONE (B, N_SCAL + 2·S + N) matrix, so a batch
 # crosses the device boundary as 3 leaves (scal, finish_s, bneck_code) —
 # per-leaf transfer + pytree overhead was a measurable slice of the
-# explorer's serial iteration. Column order IS ``core.scal_layout`` (the
-# single source of truth the Pallas kernel's packed block also derives
-# from), so on the kernel path the ops-layer unpack and this repack fold
-# to a no-op under jit and a future column lands identically in both —
-# `python -m repro.analysis` contract ``scal-cols`` guards the coupling.
-# Fixed columns first (the SCAL_PREFIX scalars, then bneck_kind_s, then
-# the top-bottleneck slot pair); the per-block bottleneck-seconds
-# telemetry (pe_bneck_s, mem_bneck_s — S padded slots each — then
-# noc_bneck_s over the N padded chain positions) rides in the
-# variable-width tail, split on host via the batch's recorded (S, N) dims.
-# (_SCAL_COLS / _N_FIXED_SCAL and the unpack indices are imported from
-# core.scal_layout at the top of this module.)
+# explorer's serial iteration. Fixed columns first, in SCAL_COLS order; the
+# per-block bottleneck-seconds telemetry (pe_bneck_s, mem_bneck_s — S
+# padded slots each — then noc_bneck_s over the N padded chain positions)
+# rides in the variable-width tail, split on host via the batch's recorded
+# (S, N) dims and never named here.
+
+# the named host-unpack scalars
+SCAL_PREFIX = (
+    "latency_s", "energy_j", "power_w", "area_mm2", "fitness",
+    "alp_time_s", "traffic_bytes", "n_phases", "all_done",
+)
+# comp-vs-comm attribution split, unpacked as one ``bneck_kind_s`` (B, 3)
+# column block
+BNECK_KIND_COLS = ("kind_pe_s", "kind_mem_s", "kind_noc_s")
+# argmax slots of the per-block bottleneck-seconds telemetry — the block a
+# bottleneck-relaxation policy should target next, computed on device
+TOP_BNECK_COLS = ("top_bneck_pe", "top_bneck_mem")
+SCAL_COLS = SCAL_PREFIX + BNECK_KIND_COLS + TOP_BNECK_COLS
+N_SCAL = len(SCAL_COLS)
+# host-unpack indices, derived from the tuples above
+KIND_START = len(SCAL_PREFIX)
+KIND_STOP = KIND_START + len(BNECK_KIND_COLS)
+TOP_PE_COL = KIND_STOP
+TOP_MEM_COL = TOP_PE_COL + 1
 
 
 class _JaxBatch:
@@ -607,12 +608,12 @@ class _JaxBatch:
         """The fetched outputs as the standard output keys (zero-copy
         column views), with the batch's non-finite rows counted."""
         scal = raw["scal"]
-        host = {name: scal[:, i] for i, name in enumerate(_SCAL_COLS)}
-        host["bneck_kind_s"] = scal[:, _KIND_START:_KIND_STOP]
-        host["top_bneck_pe"] = scal[:, _TOP_PE_COL]
-        host["top_bneck_mem"] = scal[:, _TOP_MEM_COL]
+        host = {name: scal[:, i] for i, name in enumerate(SCAL_PREFIX)}
+        host["bneck_kind_s"] = scal[:, KIND_START:KIND_STOP]
+        host["top_bneck_pe"] = scal[:, TOP_PE_COL]
+        host["top_bneck_mem"] = scal[:, TOP_MEM_COL]
         s_busy, n_noc = self.dims
-        f = _N_FIXED_SCAL
+        f = N_SCAL
         host["pe_bneck_s"] = scal[:, f:f + s_busy]
         host["mem_bneck_s"] = scal[:, f + s_busy:f + 2 * s_busy]
         host["noc_bneck_s"] = scal[:, f + 2 * s_busy:f + 2 * s_busy + n_noc]
@@ -739,21 +740,8 @@ class JaxBatchedBackend:
     back to the Python simulator per design, inside the same
     ``evaluate_candidates`` call.
 
-    Two device formulations of the same math sit behind the jit cache:
-
-      * ``use_kernel=False`` — `phase_sim_jax.simulate_batch`, the `vmap`-of-
-        `fori_loop` XLA path;
-      * ``use_kernel=True`` — the fused Pallas launch
-        (`repro.kernels.phase_sim`): one kernel over the (B, T) grid with
-        the co-residency masks in VMEM scratch (Mosaic on TPU, interpret
-        mode on CPU — recorded as ``stats().kernel_interpret``).
-
-    ``use_kernel=None`` is the XLA path on every platform, TPU included: on
-    a v5e the kernel's one-candidate-per-program grid took 9.988 ms of
-    device time per ``ar_complex`` batch of 256 and 5.62 ms per ``audio``
-    batch, against 0.399 and 0.186 ms for the XLA path. The kernel is
-    reached only by name (``use_kernel=True``, the ``"pallas"`` /
-    ``"jax_pallas"`` registry names) or with ``REPRO_PHASE_SIM_KERNEL=1``.
+    The device prices every batch through `phase_sim_jax.simulate_batch`,
+    the `vmap`-of-`fori_loop` XLA path.
 
     Dispatch is asynchronous and multi-dispatch-capable:
     ``evaluate_candidates`` returns after submission, host batch buffers are
@@ -767,29 +755,12 @@ class JaxBatchedBackend:
     name = "jax"
     async_dispatch = True  # dispatch returns before the device scores it
 
-    def __init__(
-        self, tdg: TaskGraph, db: HardwareDatabase,
-        use_kernel: Optional[bool] = None,
-    ) -> None:
-        import os
-
-        import jax
-
+    def __init__(self, tdg: TaskGraph, db: HardwareDatabase) -> None:
         from .phase_sim_jax import EncodedWorkload
 
         self.tdg = tdg
         self.db = db
         self._enc = EncodedWorkload.of(tdg)
-        if use_kernel is None:
-            env = os.environ.get("REPRO_PHASE_SIM_KERNEL", "").lower()
-            use_kernel = env in ("1", "true")
-        self._use_kernel = bool(use_kernel)
-        # Mosaic compiles the kernel for TPU only; the CPU interprets it,
-        # and any other platform fails to compile it rather than silently
-        # falling back to the interpreter
-        self._interpret = self._use_kernel and jax.default_backend() == "cpu"
-        if self._use_kernel:
-            self.name = "jax_pallas"
         self._jit = None  # single kernel: shapes vary only via padded buckets
         # shape bucket -> two alternating host rows buffers (double-buffered
         # so a fresh encode never mutates what the device may still read)
@@ -810,7 +781,7 @@ class JaxBatchedBackend:
         # the design ref doubles as an identity guard against id() reuse
         self._adopted: Dict[int, tuple] = {}
         self._shapes: set = set()
-        self._stats = BackendStats(kernel_interpret=self._interpret)
+        self._stats = BackendStats()
         # device-resident chain runner (device_explore) — built lazily so
         # host-loop users never pay for it; shares the workload encoding
         self._chains = None
@@ -868,15 +839,12 @@ class JaxBatchedBackend:
     def chain_runner(self):
         """The lazily-built :class:`~repro.core.device_explore.
         DeviceChainRunner` this backend prices chain blocks with. Shares the
-        workload encoding and kernel selection; owns its own jit cache and
+        workload encoding; owns its own jit cache and
         compile/fallback counters (the bench smoke guard asserts on them)."""
         if self._chains is None:
             from .device_explore import DeviceChainRunner
 
-            self._chains = DeviceChainRunner(
-                self.tdg, self.db, self._enc,
-                use_kernel=self._use_kernel, interpret=self._interpret,
-            )
+            self._chains = DeviceChainRunner(self.tdg, self.db, self._enc)
         return self._chains
 
     def run_chains(self, req):
@@ -958,28 +926,21 @@ class JaxBatchedBackend:
             import jax
             import jax.numpy as jnp
 
-            if self._use_kernel:
-                from ..kernels.phase_sim import phase_sim
-
-                sim = lambda rows: phase_sim(self._enc, rows, interpret=self._interpret)
-            else:
-                from .phase_sim_jax import simulate_batch
-
-                sim = lambda rows: simulate_batch(self._enc, rows)
+            from .phase_sim_jax import simulate_batch
 
             def packed(rows):
                 # pack the per-design scalars into one (B, 14 + 2·S) matrix
-                # on device (_SCAL_COLS + bneck_kind_s + top-bottleneck slot
+                # on device (SCAL_PREFIX + bneck_kind_s + top-bottleneck slot
                 # pair + the per-slot bottleneck telemetry): 3 output leaves
                 # per dispatch (wl_latency_s is dropped — the lazy decode
                 # recomputes per-workload latency from finish times on
                 # host). Free under jit: XLA fuses the stack.
-                out = sim(rows)
+                out = simulate_batch(self._enc, rows)
                 scal = jnp.stack(
                     [
                         out[k] if out[k].dtype == jnp.float32
                         else out[k].astype(jnp.float32)
-                        for k in _SCAL_COLS
+                        for k in SCAL_PREFIX
                     ],
                     axis=1,
                 )
@@ -1395,18 +1356,9 @@ class JaxBatchedBackend:
         )
 
 
-def _jax_pallas_backend(tdg: TaskGraph, db: HardwareDatabase) -> "JaxBatchedBackend":
-    return JaxBatchedBackend(tdg, db, use_kernel=True)
-
-
 BACKENDS = {
     "python": PythonBackend,
     "jax": JaxBatchedBackend,
-    "jax_batched": JaxBatchedBackend,
-    # fused Pallas phase-sim kernel (Mosaic on TPU; interpret mode on CPU),
-    # off the default path on every platform and reached only by name
-    "pallas": _jax_pallas_backend,
-    "jax_pallas": _jax_pallas_backend,
 }
 
 

@@ -30,8 +30,8 @@ module moves the whole explore step onto the device:
     provenance, the (T, cap) acceleration table, fitness, PRNG key,
     per-move taboo TTLs, and the incumbent bottleneck telemetry.
   * Chain populations: the R chains ARE the batch axis of the simulator —
-    each scan step prices an (R,)-rows dict through the usual batched
-    path (Pallas kernel or XLA reference; ``kernels.phase_sim.chain``).
+    each scan step prices an (R,)-rows dict through the batched XLA
+    simulator (``kernels.phase_sim.chain.resimulate_chains``).
     Per-chain PRNG keys are ``fold_in(base_key, chain_index)``, so chain
     i's stream — and therefore its accepted-move sequence — is identical
     at R=16 and R=256 (population size never perturbs a chain).
@@ -575,15 +575,10 @@ class DeviceChainRunner:
         g: TaskGraph,
         db: HardwareDatabase,
         enc: Optional[EncodedWorkload] = None,
-        *,
-        use_kernel: bool = False,
-        interpret: bool = False,
     ):
         self.g = g
         self.db = db
         self.enc = enc if enc is not None else EncodedWorkload.of(g)
-        self.use_kernel = use_kernel
-        self.interpret = interpret
         self._blocks: Dict[tuple, object] = {}
         self.n_compiles = 0
         self.n_fallback = 0
@@ -735,7 +730,6 @@ class DeviceChainRunner:
         from ..kernels.phase_sim.chain import resimulate_chains
 
         enc = self.enc
-        use_kernel, interpret = self.use_kernel, self.interpret
         t = len(enc.names)
         segments = MoveTable.layout(t, cap_pe, cap_mem, n_noc, alloc)
         tidx = jnp.arange(t)
@@ -874,9 +868,7 @@ class DeviceChainRunner:
                     ):
                         rows[f] = getattr(cand, f)
                 with jax.named_scope("chain.price"):
-                    res = resimulate_chains(
-                        enc, rows, use_kernel=use_kernel, interpret=interpret
-                    )
+                    res = resimulate_chains(enc, rows)
                     f_new = res["fitness"].astype(jnp.float32)
                 with jax.named_scope("chain.accept"):
                     # SA accept, f32 mirror of PolicyBase.accept; chains whose

@@ -554,12 +554,9 @@ def fill_budget(
 def simulate_one(enc: EncodedWorkload, row: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:  # repro: traced
     """Phase simulation + device-side scoring of ONE candidate row.
 
-    This is the single-candidate oracle shared by :func:`simulate_batch`
-    (``vmap`` over the row axis — the XLA reference path) and by
-    ``repro.kernels.phase_sim`` (the fused Pallas kernel reimplements this
-    math per grid program; parity ≤ 1e-5 is asserted in
-    tests/test_phase_sim_kernel.py). See :func:`simulate_batch` for the
-    contract and the co-residency-matvec formulation notes.
+    :func:`simulate_batch` is its ``vmap`` over the row axis. See
+    :func:`simulate_batch` for the contract and the co-residency-matvec
+    formulation notes.
     """
     t = enc.work_ops.shape[0]
     n_wl = len(enc.wl_names)
@@ -844,11 +841,6 @@ def simulate_batch(  # repro: traced
     are congruent mod ``n_links`` — which is exact for *any* link count
     (the old segment-bucketed formulation silently dropped the bandwidth
     attribution of links ≥ its hardcoded segment count).
-
-    This is the XLA *reference* path; ``repro.kernels.phase_sim`` provides
-    the fused Pallas formulation of the same math (one launch over the
-    (B, T) grid, Mosaic on TPU / interpret on CPU) selected via
-    ``JaxBatchedBackend(use_kernel=True)``.
     """
     return jax.vmap(lambda row: simulate_one(enc, row))(rows)
 

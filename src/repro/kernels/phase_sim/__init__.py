@@ -1,14 +1,4 @@
-"""Fused phase-driven simulator kernel (Pallas).
-
-One launch prices a whole candidate batch: grid over the batch axis, each
-program running the full ≤T-phase loop of one design with the (T, T)
-co-residency masks staged in VMEM scratch. ``ops.phase_sim`` is the
-drop-in counterpart of ``repro.core.phase_sim_jax.simulate_batch`` (same
-rows-dict in, same output dict out); ``ref.phase_sim_ref`` is the pure-jnp
-oracle the kernel is tested against (tests/test_phase_sim_kernel.py).
-"""
+"""Pricing seam of the device chain step (``chain.resimulate_chains``)."""
 from .chain import resimulate_chains
-from .ops import phase_sim
-from .ref import phase_sim_ref
 
-__all__ = ["phase_sim", "phase_sim_ref", "resimulate_chains"]
+__all__ = ["resimulate_chains"]

@@ -1,24 +1,15 @@
 """In-loop re-simulation for the device-resident explorer.
 
-The fused accept loop (``repro.core.device_explore``) mutates R chain
-encodings per iteration and needs their ``fitness`` plus the per-slot
-bottleneck telemetry columns (``pe_bneck_s``/``mem_bneck_s``) back *inside*
-the same ``lax.scan`` step — no host round trip. The chains ARE the batch
-axis: every scan iteration prices an (R,)-rows dict, which is exactly the
-contract of the batched simulator, so the device loop routes through the
-fused Pallas kernel (``ops.phase_sim``) when the backend runs with the
-kernel enabled and through the XLA reference (``simulate_batch``)
-otherwise. Both return the same output dict, which keeps the scan body
-layout-agnostic: the carry never stores kernel-specific packing.
+The fused accept loop (``repro.core.device_explore``) prices its R mutated
+chain encodings inside each ``lax.scan`` step through ``resimulate_chains``.
+It reads ``simulate_batch`` as this module's own name so that a test can
+patch the simulator the chain step prices through (the benchmark's fault
+test does).
 
-Mixed mapping+allocation chains price through the SAME call: allocation
-moves are shape-preserving over capacity-padded slot inventories, so a
-fork/join/swap/NoC-attach step still hands this function an (R,)-rows dict
-— the per-slot coefficient columns are (R, cap) wide with
-``pe_active``/``mem_active`` masks pricing inactive slots as absent (zero
-leak/area contribution, pad-neutral rates). Nothing here distinguishes a
-mapping-only step from a mixed one; the move semantics live entirely in
-the carry mutations upstream.
+Mixed mapping+allocation chains price through the same call: allocation
+moves are shape-preserving over capacity-padded slot inventories, so every
+step hands this function an (R,)-rows dict, with ``pe_active``/
+``mem_active`` masks pricing inactive slots as absent.
 """
 from __future__ import annotations
 
@@ -27,24 +18,13 @@ from typing import Dict
 import jax.numpy as jnp
 
 from ...core.phase_sim_jax import EncodedWorkload, simulate_batch
-from .ops import phase_sim
 
 __all__ = ["resimulate_chains"]
 
 
 def resimulate_chains(  # repro: traced
-    enc: EncodedWorkload,
-    rows: Dict[str, jnp.ndarray],
-    *,
-    use_kernel: bool = False,
-    interpret: bool = False,
+    enc: EncodedWorkload, rows: Dict[str, jnp.ndarray]
 ) -> Dict[str, jnp.ndarray]:
-    """Price the R mutated chain encodings of one accept-loop iteration.
-
-    ``rows`` is a batched rows dict with the chain axis leading (R designs,
-    one per chain). Traced inside the chain scan body, so it must stay a
-    pure function of its array inputs — it is, both branches are.
-    """
-    if use_kernel:
-        return phase_sim(enc, rows, interpret=interpret)
+    """Price the R mutated chain encodings of one accept-loop iteration
+    (``rows`` has the chain axis leading)."""
     return simulate_batch(enc, rows)

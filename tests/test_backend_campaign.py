@@ -17,7 +17,7 @@ from repro.core import (
     make_backend,
     random_single_noc_designs,
 )
-from repro.core.backend import BackendStats
+from repro.core.backend import SCAL_COLS, BackendStats
 from repro.core.backend import Candidate as JaxCandidate
 from repro.core.blocks import make_gpp, make_noc
 
@@ -320,3 +320,62 @@ def test_campaign_duplicate_name_rejected():
     # refused rather than silently overridden
     with pytest.raises(ValueError):
         camp.add("b", g, bud, ExplorerConfig(max_iterations=5, backend="jax"))
+
+
+# ---- packed scal layout ----------------------------------------------------
+# simulate_batch's bneck_kind_s columns, in its own order
+_KIND_COL = {"kind_pe_s": 0, "kind_mem_s": 1, "kind_noc_s": 2}
+
+
+def _named(out, col):
+    """The output of ``simulate_batch`` (or of the host unpack) named ``col``."""
+    if col in _KIND_COL:
+        return out["bneck_kind_s"][:, _KIND_COL[col]]
+    return out[col]
+
+
+@pytest.fixture(scope="module")
+def audio_packed():
+    """One audio batch priced twice from the same rows: ``simulate_batch``'s
+    named outputs, and the backend's packed dispatch with its host unpack."""
+    import jax
+    import numpy as np
+
+    from repro.core.backend import _JaxBatch
+    from repro.core.phase_sim_jax import encode_batch, fill_budget, simulate_batch
+
+    db = HardwareDatabase()
+    g = audio()
+    bud = calibrated_budget(db)
+    jb = JaxBatchedBackend(g, db)
+    designs = random_single_noc_designs(g, 8, seed=1)
+    slots = max(max(len(d.pes()), len(d.mems())) for d in designs)
+    rows = encode_batch(designs, g, db, jb._enc, n_pe=slots, n_mem=slots)
+    for j in range(len(designs)):
+        fill_budget(rows, j, jb._enc, bud.latency_s, bud.power_w, bud.area_mm2, 0.05)
+    ref = jax.device_get(jax.jit(lambda r: simulate_batch(jb._enc, r))(rows))
+    # no two named columns agree on this batch, so a column that lands
+    # under another's name cannot pass by coincidence
+    cols = [np.asarray(_named(ref, c), np.float32) for c in SCAL_COLS]
+    assert not any(
+        np.array_equal(a, b) for i, a in enumerate(cols) for b in cols[i + 1:]
+    )
+    packed = jb._fn()(rows)
+    raw = np.asarray(jax.device_get(packed["scal"]))
+    dims = (slots, rows["noc_bw"].shape[1])
+    host = _JaxBatch(packed, BackendStats(), [None] * len(designs), dims).host()
+    return ref, raw, host
+
+
+@pytest.mark.parametrize("col", SCAL_COLS)
+def test_packed_scal_column_lands_under_its_name(audio_packed, col):
+    """The packed scal matrix holds ``simulate_batch``'s output named ``col``
+    at ``SCAL_COLS.index(col)``, and the host unpack returns it under that
+    name (the kind triple as ``bneck_kind_s``, the top-bottleneck pair as
+    ``top_bneck_pe``/``top_bneck_mem``)."""
+    import numpy as np
+
+    ref, raw, host = audio_packed
+    want = np.asarray(_named(ref, col), np.float32)
+    np.testing.assert_allclose(raw[:, SCAL_COLS.index(col)], want, rtol=1e-6)
+    np.testing.assert_allclose(_named(host, col), want, rtol=1e-6)

@@ -2,11 +2,10 @@
 tier-1 CI. The smoke mode prices one neighbour-candidate batch through both
 backends at tiny sizes and *asserts* (1) the JAX array-native path is at
 least as fast as the scalar Python path, (2) both agree on the winning
-candidate's latency, (3) the fused Pallas phase-sim kernel matches the XLA
-reference path ≤ 1e-5 on the fitness column, and (4) the device-loop
-guard: the fused (R=16, K) chain block sustains ≥ 2x the host-driven
-loop's chain-iteration rate with ``n_compiles ≤ 4`` and ``n_fallback ==
-0``, replaying the host loop bit-for-bit at R=1 — and (5) the same
+candidate's latency, and (3) the device-loop guard: the fused (R=16, K)
+chain block sustains ≥ 2x the host-driven loop's chain-iteration rate with
+``n_compiles ≤ 4`` and ``n_fallback == 0``, replaying the host loop
+bit-for-bit at R=1 — and (4) the same
 contract for the mixed mapping+allocation block on the widened move table
 (R=1 parity, ≥ 2x at R=16, ``n_compiles ≤ 6``, ``n_fallback == 0``) —
 while the retired speculative-pipeline counters stay absent from

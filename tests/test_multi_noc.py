@@ -1,6 +1,6 @@
 """Array-native multi-NoC regime: forked/joined chain designs price
 identically through the scalar Python simulator and the batched JAX backend
-(XLA and Pallas-kernel paths), topology-move-enabled explorations never hit
+on both paper workloads, topology-move-enabled explorations never hit
 the scalar fallback, and the development-cost policy lands the §5.3
 complexity-reduction comparison through ``Campaign.aggregate``."""
 import random
@@ -65,17 +65,17 @@ def chain_designs(g, n_noc: int, count: int, seed: int = 0):
 @pytest.mark.parametrize(
     "batch", [1, 8, pytest.param(64, marks=pytest.mark.slow)]
 )
-@pytest.mark.parametrize("use_kernel", [False, True])
-def test_multi_noc_parity_python_vs_jax(n_noc, batch, use_kernel):
+@pytest.mark.parametrize("graph_fn", [audio, ar_complex])
+def test_multi_noc_parity_python_vs_jax(n_noc, batch, graph_fn):
     """Forked chains (N ∈ {2, 3}) priced by PythonBackend vs
-    JaxBatchedBackend — XLA and Pallas, B ∈ {1, 8, 64} — agree ≤ 1e-5 on
-    latency, per-task finish times, PPA, fitness, and the Algorithm-1
-    bottleneck attribution."""
+    JaxBatchedBackend — audio and ar_complex, B ∈ {1, 8, 64} — agree
+    ≤ 1e-5 on latency, per-task finish times, PPA, fitness, and the
+    Algorithm-1 bottleneck attribution."""
     db = HardwareDatabase()
-    g = audio()
+    g = graph_fn()
     bud = calibrated_budget(db)
     designs = chain_designs(g, n_noc, batch, seed=10 * n_noc + batch)
-    jb = JaxBatchedBackend(g, db, use_kernel=use_kernel)
+    jb = JaxBatchedBackend(g, db)
     cands = [Candidate.of_design(d, bud) for d in designs]
     handles = jb.evaluate_candidates(cands)
     assert jb.stats().n_fallback == 0 and jb.stats().n_batched == batch

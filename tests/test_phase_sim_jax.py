@@ -4,16 +4,19 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import Design, HardwareDatabase, ar_complex, edge_detection, random_single_noc_designs, simulate
+from repro.core import Design, HardwareDatabase, ar_complex, audio, edge_detection, random_single_noc_designs, simulate
 from repro.core.phase_sim_jax import EncodedWorkload, encode_batch, simulate_batch
 
 
-@pytest.mark.parametrize("graph_fn", [edge_detection, ar_complex])
-def test_vectorized_matches_python(graph_fn):
+@pytest.mark.parametrize("graph_fn", [edge_detection, audio, ar_complex])
+@pytest.mark.parametrize(
+    "batch", [1, 8, pytest.param(64, marks=pytest.mark.slow)]
+)
+def test_vectorized_matches_python(graph_fn, batch):
     db = HardwareDatabase()
     g = graph_fn()
     enc = EncodedWorkload.of(g)
-    designs = random_single_noc_designs(g, 8, seed=3)
+    designs = random_single_noc_designs(g, batch, seed=3)
     rows = encode_batch(designs, g, db, enc)
     out = jax.jit(lambda r: simulate_batch(enc, r))(rows)
     assert bool(out["all_done"].all())

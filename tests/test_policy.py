@@ -174,16 +174,22 @@ def test_policy_registry_and_config_selection():
 # telemetry parity: device columns vs host SimResult attribution
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("graph_fn,seed", [(audio, 3), (ar_complex, 5)])
-@pytest.mark.parametrize("use_kernel", [False, True])
-def test_bottleneck_telemetry_matches_host_attribution(graph_fn, seed, use_kernel):
+@pytest.mark.parametrize("n_noc", [1, 2])
+def test_bottleneck_telemetry_matches_host_attribution(graph_fn, seed, n_noc):
     """Acceptance bar: the device-side per-block bottleneck telemetry agrees
     with the Python simulator's host attribution to ≤ 1e-5 (relative to the
-    design's latency), on the XLA and the Pallas-kernel path alike; the
-    top-bottleneck argmax column resolves to the same block."""
+    design's latency), on single-NoC designs and on forked 2-NoC chains;
+    the top-bottleneck argmax column resolves to the same block."""
+    # the forked chains are built as the multi-NoC parity test builds them
+    from test_multi_noc import chain_designs
+
     db = HardwareDatabase()
     g = graph_fn()
-    jb = JaxBatchedBackend(g, db, use_kernel=use_kernel)
-    designs = random_single_noc_designs(g, 6, seed=seed)
+    jb = JaxBatchedBackend(g, db)
+    if n_noc == 1:
+        designs = random_single_noc_designs(g, 6, seed=seed)
+    else:
+        designs = chain_designs(g, n_noc, 6, seed=seed)
     handles = jb.evaluate_candidates([Candidate.of_design(d) for d in designs])
     for d, h in zip(designs, handles):
         ref = simulate(d, g, db)
