@@ -551,12 +551,24 @@ def fill_budget(
     rows["alpha"][j] = alpha
 
 
+def _slot_total(x: jnp.ndarray, onehot: jnp.ndarray) -> jnp.ndarray:
+    """Each task's total of ``x`` (T,) over the tasks on its own slot: the
+    (S,) slot sums through the (T, S) one-hot task→slot map, picked back per
+    task. O(T·S) where a same-slot (T, T) mask is O(T²), and the same sums:
+    other slots' terms are exact zeros, and so are all but one of the terms
+    of each pick. Both dots take HIGHEST precision, since ``x`` and the slot
+    totals are not 0/1 and a default-precision f32 dot may round its
+    operands to bfloat16 on the TPU."""
+    hi = jax.lax.Precision.HIGHEST
+    return jnp.dot(onehot, jnp.dot(x, onehot, precision=hi), precision=hi)
+
+
 def simulate_one(enc: EncodedWorkload, row: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:  # repro: traced
     """Phase simulation + device-side scoring of ONE candidate row.
 
     :func:`simulate_batch` is its ``vmap`` over the row axis. See
-    :func:`simulate_batch` for the contract and the co-residency-matvec
-    formulation notes.
+    :func:`simulate_batch` for the contract and how the phase body sums
+    contention and tests readiness.
     """
     t = enc.work_ops.shape[0]
     n_wl = len(enc.wl_names)
@@ -570,17 +582,20 @@ def simulate_one(enc: EncodedWorkload, row: Dict[str, jnp.ndarray]) -> Dict[str,
     # the loop-invariant hoists and the phase loop are device scopes of
     # their own (repro.runtime.spans), so a trace splits the two
     with jax.named_scope("phase_sim.setup"):
-        # loop-invariant hoists: effective peak rates per task and the
-        # same-slot co-residency masks behind Eq. 1/2 (PE share) and Eq. 4
-        # (burst-proportional memory share)
+        # loop-invariant hoists: effective peak rates per task
         peak_eff = row["pe_peak"][task_pe] * row["pe_accel"]
         mem_peak = row["mem_bw"][task_mem]
-        same_pe = (task_pe[:, None] == task_pe[None, :]).astype(jnp.float32)
-        same_mem = (task_mem[:, None] == task_mem[None, :]).astype(jnp.float32)
-        # one-hot task→slot maps: cap rollup and the per-slot bottleneck
-        # telemetry accumulate through these instead of segment_sum scatters
+        # one-hot task→slot maps (T, S): the per-phase contention sums of
+        # Eq. 1/2 (PE share) and Eq. 4 (burst-proportional memory share),
+        # the cap rollup and the per-slot bottleneck telemetry all go
+        # through these, O(T·S) and without segment_sum scatters
         onehot_pe = (task_pe[:, None] == jnp.arange(n_pe)[None, :]).astype(jnp.float32)
         onehot_mem = (task_mem[:, None] == jnp.arange(n_mem)[None, :]).astype(jnp.float32)
+        # the parent mask as 0/1 floats: a task's open parents are counted by
+        # one matvec per phase. The mask is the workload's, shared by every
+        # row, so under vmap that is one (R, T) × (T, T) matmul on the MXU;
+        # 0/1 operands and counts ≤ T keep it exact at any precision
+        parent_f = enc.parent_mask.astype(jnp.float32)
         links = jnp.maximum(row["noc_links"], 1)  # (N,)
         # multi-NoC chain routing: a task's route is the chain-index interval
         # between its PE's and its MEM's NoC; hop count scales the NoC energy
@@ -624,8 +639,7 @@ def simulate_one(enc: EncodedWorkload, row: Dict[str, jnp.ndarray]) -> Dict[str,
             order = jnp.cumsum(use_k)
             link = jnp.where(use_k > 0, (order - 1.0) % links[k], -1.0)
             oh = (link[:, None] == lidx[None, :]).astype(jnp.float32)
-            link_load = (enc.burst * use_k) @ oh  # (8,) burst per link
-            link_t = oh @ link_load
+            link_t = _slot_total(enc.burst * use_k, oh)  # burst on my link
             bw_k = jnp.where(
                 use_k > 0,
                 noc_bw[k] * enc.burst / jnp.maximum(link_t, 1e-30),
@@ -639,17 +653,19 @@ def simulate_one(enc: EncodedWorkload, row: Dict[str, jnp.ndarray]) -> Dict[str,
     def phase(_, state):
         (rem_ops, rem_rd, rem_wr, completed, now, finish, bneck, bneck_noc,
          kind_s, pe_bt, mem_bt, noc_bt, alp_t, traffic, nph) = state
-        running = (~completed) & jnp.all(~enc.parent_mask | completed[None, :], axis=1)
+        # ready = every parent completed, i.e. no open parent left
+        open_parents = parent_f @ jnp.where(completed, 0.0, 1.0)
+        running = (~completed) & (open_parents == 0.0)
         runf = jnp.where(running, 1.0, 0.0)
         burst_run = enc.burst * runf
 
         # Eq. 1/2: preemptive equal share per PE slot
-        load_t = same_pe @ runf  # running tasks sharing my PE (incl. me)
+        load_t = _slot_total(runf, onehot_pe)  # running tasks on my PE (incl. me)
         compute = peak_eff / jnp.maximum(load_t, 1.0)
 
         # Eq. 4: burst-proportional memory share (read/write channels
         # split, but they see identical shares — one bandwidth suffices)
-        mem_t = same_mem @ burst_run
+        mem_t = _slot_total(burst_run, onehot_mem)
         m_bw = mem_peak * enc.burst / jnp.maximum(mem_t, 1e-30)
 
         # Eq. 3: per-NoC link striping, end-to-end min over the route
@@ -685,9 +701,11 @@ def simulate_one(enc: EncodedWorkload, row: Dict[str, jnp.ndarray]) -> Dict[str,
         # resolved after the loop. One NoC: it is just kind_s[2], resolved
         # post-loop; multi-NoC: one (T,N) masked matvec per phase.
         if n_noc > 1:
-            noc_bt = noc_bt + jnp.where(code == 2, phi_run, 0.0) @ (
-                noc_arg[:, None] == nidx[None, :]
-            ).astype(jnp.float32)
+            noc_bt = noc_bt + jnp.dot(
+                jnp.where(code == 2, phi_run, 0.0),
+                (noc_arg[:, None] == nidx[None, :]).astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            )
 
         # mask rates BEFORE the phi multiply: slots hosting no running
         # task price as inf bandwidth, and inf · 0 would poison the
@@ -832,15 +850,20 @@ def simulate_batch(  # repro: traced
     or rejecting a whole neighbour batch transfers O(B) floats, not B
     decoded dicts.
 
-    Contention sums are (T, T) co-residency matvecs, not ``segment_sum``
-    scatters: ``task_pe``/``task_mem`` are phase-invariant so the same-slot
-    masks hoist out of the loop, and vmapped scatter/gather pairs are the
-    dominant cost of the phase loop on CPU XLA (~4x kernel time). NoC
-    round-robin striping (Eq. 3) is expressed the same way through rank
-    residues — two running tasks share a link iff their running-order ranks
-    are congruent mod ``n_links`` — which is exact for *any* link count
-    (the old segment-bucketed formulation silently dropped the bandwidth
-    attribution of links ≥ its hardcoded segment count).
+    No phase does per-row (T, T) work. Contention sums (a task's running
+    neighbours on its PE, the bursts on its memory) are per-slot sums
+    through the phase-invariant (T, S) one-hot task→slot maps, picked back
+    per task (:func:`_slot_total`): O(T·S) a phase, not ``segment_sum``
+    scatters, whose vmapped scatter/gather pairs cost ~4x on CPU XLA, nor
+    (T, T) same-slot masks, which XLA rebuilds per row in every phase.
+    Readiness counts each task's open parents as ``parent_mask @ (1 −
+    completed)``: the mask is the workload's, shared by all rows, so under
+    ``vmap`` it is one (B, T) × (T, T) matmul a phase on the MXU, exact
+    since its operands are 0/1 and its counts at most T. NoC round-robin
+    striping (Eq. 3) goes through rank residues — two running tasks share a
+    link iff their running-order ranks are congruent mod ``n_links`` —
+    which is exact for *any* link count; on more than one NoC the links are
+    a (T, 8) one-hot, on one NoC the historic (T, T) form.
     """
     return jax.vmap(lambda row: simulate_one(enc, row))(rows)
 

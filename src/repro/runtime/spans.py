@@ -49,7 +49,7 @@ DEVICE_SCOPES = (
     "chain.apply",  # apply the drawn move, build the candidate's rows
     "chain.price",  # phase simulation of the candidate (kernel or XLA path)
     "chain.accept",  # SA accept and carry swap
-    "phase_sim.setup",  # loop-invariant hoists: co-residency masks, one-hots, routes
+    "phase_sim.setup",  # loop-invariant hoists: per-task rates, one-hots, routes
     "phase_sim.phases",  # the phase loop
 )
 
